@@ -1,4 +1,4 @@
-"""Shared benchmark utilities: structured QKV generators + timing."""
+"""Shared benchmark utilities: structured QKV generators, timing, row output."""
 from __future__ import annotations
 
 import time
@@ -8,6 +8,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mra import full_attention
+from repro.launch.device import device_summary
+
+# every row names the device it ran on: a CPU row (interpret-mode kernels
+# included) is never a device measurement
+CSV_HEADER = "name,us_per_call,derived,device"
+
+
+def emit_row(name, us, derived) -> None:
+    d = device_summary()
+    print(f"{name},{us:.1f},{derived},platform={d['platform']} "
+          f"kind={d['kind']} count={d['count']}", flush=True)
 
 
 def structured_qkv(rng, B=1, H=8, N=512, D=64, *, n_clusters=12, locality=0.7,

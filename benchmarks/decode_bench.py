@@ -20,11 +20,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.attention import AttentionSpec, decode_attention
 from repro.distributed import mesh_utils
 from repro.distributed.shard_attn import attention_partition
+from repro.launch.device import kernel_interpret
 
-from .common import structured_qkv, time_call
+from .common import CSV_HEADER, emit_row, structured_qkv, time_call
 
 
-def run(emit):
+def run(emit, interpret=False):
+    interpret = kernel_interpret(interpret)
     rng = np.random.default_rng(3)
     B, Hq, Hkv, S, D, b = 4, 8, 2, 4096, 64, 32
     _, k, v = structured_qkv(rng, B=B, H=Hkv, N=S, D=D)
@@ -95,10 +97,9 @@ def run(emit):
 
     # fused Pallas serving kernel rows (DESIGN.md §11): same selection, the
     # gather + two-level softmax + background + normalize fused on-chip.
-    # Interpret mode off-TPU, so the absolute time only proves the path runs
-    # end-to-end; the kernel-vs-jnp ratio is meaningful on real TPUs. The
-    # derived column doubles as the online parity check vs the jnp rows.
-    interpret = jax.devices()[0].platform != "tpu"
+    # Under --interpret the absolute time only proves the path runs
+    # end-to-end. The derived column doubles as the online parity check vs
+    # the jnp rows.
     kspec = AttentionSpec(kind="mra2", block_size=b, decode_blocks=16,
                           use_kernel=True, interpret=interpret, shard=shard)
     out_k = decode_attention(q, k, v, lengths, kspec)
@@ -118,23 +119,20 @@ def run(emit):
 
 def main() -> None:
     import argparse
-    import sys
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="1",
                     help="device mesh 'D' or 'DxM' (default: 1 = no mesh)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernel rows in interpret mode "
+                         "(required off-TPU)")
     args = ap.parse_args()
 
     from repro.launch.mesh import parse_mesh
 
-    print("name,us_per_call,derived")
-
-    def emit(name, us, derived):
-        print(f"{name},{us:.1f},{derived}")
-        sys.stdout.flush()
-
+    print(CSV_HEADER)
     with mesh_utils.use_mesh(parse_mesh(args.mesh)):
-        run(emit)
+        run(emit_row, interpret=args.interpret)
 
 
 if __name__ == "__main__":

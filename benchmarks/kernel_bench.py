@@ -3,7 +3,7 @@
 Times three routes over the same inputs/selection budget:
 
   * jnp           — pure gather/scatter path (mra2_attention, no kernel)
-  * kernel        — Pallas fwd + fused Pallas bwd (interpret mode off-TPU)
+  * kernel        — Pallas fwd + fused Pallas bwd
   * kernel_jnpbwd — Pallas fwd + jnp fallback bwd (the dispatch boundary)
 
 plus the serving-side twin (PR 5, DESIGN.md §11): chunk/decode attention
@@ -14,11 +14,11 @@ to latency (single-query) tiles and chunks to throughput (multi-query MXU)
 tiles; extra rows force each mode on the chunk shape to price the tile
 choice and pin both against the jnp oracle.
 
-On a CPU host the Pallas kernels run in interpret mode, so the absolute
-numbers only demonstrate that the paths execute end-to-end; the
-kernel-vs-jnp *ratio* is only meaningful on a real TPU, where interpret
-flips to False automatically. The derived column reports the max |grad|
-difference vs the jnp path (a cheap online correctness check).
+The kernels compile for the TPU; off-TPU the run fails unless interpret
+mode is asked for explicitly (``benchmarks.run --interpret``), and then the
+absolute numbers only demonstrate that the paths execute end-to-end. The
+derived column reports the max |grad| difference vs the jnp path (a cheap
+online correctness check).
 """
 from __future__ import annotations
 
@@ -28,20 +28,17 @@ import numpy as np
 
 from repro.core.attention import AttentionSpec, chunk_attention, decode_attention
 from repro.core.mra import MraConfig, mra2_attention
+from repro.launch.device import kernel_interpret
 
 from .common import structured_qkv, time_call
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
-def run(emit):
+def run(emit, interpret=False):
     rng = np.random.default_rng(5)
-    interpret = not _on_tpu()
+    interpret = kernel_interpret(interpret)
     # interpret mode executes the kernel body per grid step in Python — keep
-    # the CPU shape small; TPU runs get a production-ish shape.
-    N, H, D, b = (512, 4, 64, 32) if _on_tpu() else (128, 2, 16, 16)
+    # its shape small; TPU runs get a production-ish shape.
+    N, H, D, b = (128, 2, 16, 16) if interpret else (512, 4, 64, 32)
     q, k, v = structured_qkv(rng, B=1, H=H, N=N, D=D)
 
     def cfg(use_kernel, bwd="pallas"):
@@ -77,8 +74,8 @@ def run(emit):
 
     # ---- serving kernel: chunk/decode attention vs the KV cache (§11) ----- #
     B, Hq, Hkv, S, Dd, bd, C, m = (
-        (4, 8, 2, 2048, 64, 32, 16, 16) if _on_tpu() else
-        (2, 4, 2, 128, 16, 16, 8, 4))
+        (2, 4, 2, 128, 16, 16, 8, 4) if interpret else
+        (4, 8, 2, 2048, 64, 32, 16, 16))
     _, kc, vc = structured_qkv(rng, B=B, H=Hkv, N=S, D=Dd)
     lengths = jnp.full((B,), S, jnp.int32)
     q_pos = jnp.broadcast_to(jnp.arange(S - C, S), (B, C))

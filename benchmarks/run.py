@@ -1,8 +1,9 @@
 """Benchmark harness — one module per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows (derived = the table's metric:
-relative error, NLL, scaling exponent, or a boolean claim check), and can
-mirror them to a JSON file (``--json``) for the CI perf-trajectory artifact.
+Prints ``name,us_per_call,derived,device`` CSV rows (derived = the table's
+metric: relative error, NLL, scaling exponent, or a boolean claim check;
+device = the platform, kind and count the row ran on), and can mirror them
+to a JSON file (``--json``) for the CI perf-trajectory artifact.
 
   approx_error  -> paper Fig. 1 + Fig. 4 / Tab. 7 (error vs budget/method)
   entropy_error -> paper Fig. 5 (error vs softmax entropy)
@@ -23,6 +24,11 @@ for the run: modules read it via ``mesh_utils.get_mesh()`` and place/shard
 their inputs accordingly (decode_bench drives the shard_map TP decode path).
 Use ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` to validate
 sharded runs on a CPU host.
+
+The Pallas kernels compile for the TPU. ``--interpret`` runs them in
+interpret mode instead; without it, a suite that needs a kernel fails on a
+host without a TPU. JAX's persistent compilation cache is on
+(``repro.launch.device.enable_compile_cache``).
 """
 import argparse
 import json
@@ -70,6 +76,9 @@ def main() -> None:
     ap.add_argument("--trace", default=None,
                     help="request-lifecycle trace JSONL output path, passed "
                          "to suites that accept trace_path (serve_bench)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in interpret mode (required "
+                         "for kernel suites off-TPU)")
     args = ap.parse_args()
 
     if args.list:
@@ -79,7 +88,12 @@ def main() -> None:
     import importlib
 
     from repro.distributed import mesh_utils
+    from repro.launch.device import device_summary, enable_compile_cache
     from repro.launch.mesh import parse_mesh
+
+    from .common import CSV_HEADER, emit_row
+
+    enable_compile_cache()
 
     chosen = args.only.split(",") if args.only else list(MODULES)
     unknown = [n for n in chosen if n not in MODULES]
@@ -90,13 +104,12 @@ def main() -> None:
                for name in chosen}
     mesh = parse_mesh(args.mesh)
 
-    print("name,us_per_call,derived")
+    print(CSV_HEADER)
     rows = []
 
     def make_emit(suite):
         def emit(name, us, derived):
-            print(f"{name},{us:.1f},{derived}")
-            sys.stdout.flush()
+            emit_row(name, us, derived)
             rows.append({"name": name, "us_per_call": us,
                          "derived": str(derived), "suite": suite})
         return emit
@@ -105,11 +118,12 @@ def main() -> None:
 
     with mesh_utils.use_mesh(mesh):
         for name in chosen:
+            params = inspect.signature(modules[name].run).parameters
             kwargs = {}
-            if (args.trace
-                    and "trace_path" in
-                    inspect.signature(modules[name].run).parameters):
+            if args.trace and "trace_path" in params:
                 kwargs["trace_path"] = args.trace
+            if "interpret" in params:
+                kwargs["interpret"] = args.interpret
             modules[name].run(make_emit(name), **kwargs)
 
     # schema check: every chosen suite must have emitted at least one row.
@@ -129,7 +143,8 @@ def main() -> None:
                  "refusing to produce a partial artifact")
 
     if args.json:
-        meta = {"mesh": args.mesh, "modules": chosen}
+        meta = {"mesh": args.mesh, "modules": chosen,
+                "device": device_summary(), "interpret": args.interpret}
         with open(args.json, "w") as f:
             json.dump({"meta": meta, "rows": rows}, f, indent=2)
         print(f"[bench] wrote {len(rows)} rows to {args.json}", file=sys.stderr)

@@ -35,9 +35,12 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.distributed import mesh_utils
+from repro.launch.device import kernel_interpret
 from repro.models import get_model, init_params
 from repro.serve import Engine, EngineConfig, Request, SamplingParams
 from repro.serve.telemetry import load_trace_jsonl, validate_chrome_events
+
+from .common import CSV_HEADER, emit_row
 
 
 def _requests(rng, vocab, lens, new_tokens):
@@ -50,7 +53,7 @@ def _requests(rng, vocab, lens, new_tokens):
     return reqs
 
 
-def _long_ctx(emit, cfg, params, mesh, *, smoke):
+def _long_ctx(emit, cfg, params, mesh, *, smoke, interpret=False):
     """H=3 collapse-up serving (DESIGN.md §14): context >> the fine window.
 
     One slot streams a prompt far past ``max_len`` through chunked prefill —
@@ -60,13 +63,12 @@ def _long_ctx(emit, cfg, params, mesh, *, smoke):
     the derived column pins the memory claim: live fine tokens stay bounded
     by the window while the tail absorbs the distant history. The smoke
     variant (scripts/ci.sh fast) shrinks the stream and routes attention
-    through the interpret-mode serving kernel so the in-kernel upper-level
-    fold is exercised end-to-end off-TPU.
+    through the serving kernel (``interpret`` mode off-TPU) so the in-kernel
+    upper-level fold is exercised end-to-end.
     """
     hcfg = cfg.replace(attention=cfg.attention.replace(levels=3))
     if smoke:
-        hcfg = hcfg.replace(attn_use_kernel=True,
-                            attn_interpret=jax.devices()[0].platform != "tpu")
+        hcfg = hcfg.replace(attn_use_kernel=True, attn_interpret=interpret)
     S, max_len, chunk = (2048, 256, 128) if smoke else (65536, 1024, 512)
     rng = np.random.default_rng(42)
     eng = Engine(hcfg, params, EngineConfig(
@@ -89,7 +91,8 @@ def _long_ctx(emit, cfg, params, mesh, *, smoke):
          f"tail_peak={tail:.0f}")
 
 
-def run(emit, trace_path=None):
+def run(emit, trace_path=None, interpret=False):
+    interpret = kernel_interpret(interpret)
     mesh = mesh_utils.get_mesh()
     cfg = get_smoke_config("qwen3-1.7b")
     cfg = cfg.replace(attn_shard=mesh is not None)
@@ -194,9 +197,8 @@ def run(emit, trace_path=None):
 
     # fused Pallas serving kernel (DESIGN.md §11): the same engine with
     # chunked prefill + decode attention routed through kernels/chunk_attn.py
-    # (interpret mode off-TPU, so treat the CPU tok/s as a does-it-run row,
-    # not a speedup claim; the derived column pins the token streams equal).
-    interpret = jax.devices()[0].platform != "tpu"
+    # (under --interpret the tok/s is a does-it-run row, not a speedup claim;
+    # the derived column pins the token streams equal).
     kcfg = cfg.replace(attn_use_kernel=True, attn_interpret=interpret)
     lens = [8, 12, 5]
     reqs = _requests(rng, cfg.vocab, lens, new_tokens)
@@ -310,7 +312,6 @@ def run(emit, trace_path=None):
 
 def main() -> None:
     import argparse
-    import sys
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="1",
@@ -320,18 +321,16 @@ def main() -> None:
                          "trace as Chrome-trace JSONL to this path")
     ap.add_argument("--long-ctx-smoke", action="store_true",
                     help="run only the H=3 collapse-up long-context smoke "
-                         "(small stream, interpret-mode kernel; the "
+                         "(small stream through the serving kernel; the "
                          "scripts/ci.sh fast leg)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas serving kernel in interpret mode "
+                         "(required off-TPU)")
     args = ap.parse_args()
 
     from repro.launch.mesh import parse_mesh
 
-    print("name,us_per_call,derived")
-
-    def emit(name, us, derived):
-        print(f"{name},{us:.1f},{derived}")
-        sys.stdout.flush()
-
+    print(CSV_HEADER)
     with mesh_utils.use_mesh(parse_mesh(args.mesh)):
         if args.long_ctx_smoke:
             mesh = mesh_utils.get_mesh()
@@ -339,9 +338,10 @@ def main() -> None:
                 attn_shard=mesh is not None)
             params = init_params(get_model(cfg).param_specs(cfg),
                                  jax.random.PRNGKey(0))
-            _long_ctx(emit, cfg, params, mesh, smoke=True)
+            _long_ctx(emit_row, cfg, params, mesh, smoke=True,
+                      interpret=kernel_interpret(args.interpret))
         else:
-            run(emit, trace_path=args.trace)
+            run(emit_row, trace_path=args.trace, interpret=args.interpret)
 
 
 if __name__ == "__main__":

@@ -34,6 +34,8 @@ from repro.distributed import mesh_utils
 from repro.models import get_model, init_params
 from repro.serve import Engine, EngineConfig, Request, SamplingParams
 
+from .common import CSV_HEADER, emit_row
+
 
 def _requests(rng, vocab):
     """Mixed greedy/sampled traffic; greedy-heavy like production serving."""
@@ -123,7 +125,6 @@ def run(emit, ks=(2, 4), assert_claim=True):
 
 def main() -> None:
     import argparse
-    import sys
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="1",
@@ -134,17 +135,12 @@ def main() -> None:
 
     from repro.launch.mesh import parse_mesh
 
-    print("name,us_per_call,derived")
-
-    def emit(name, us, derived):
-        print(f"{name},{us:.1f},{derived}")
-        sys.stdout.flush()
-
+    print(CSV_HEADER)
     with mesh_utils.use_mesh(parse_mesh(args.mesh)):
         if args.smoke:
-            run(emit, ks=(2,), assert_claim=False)
+            run(emit_row, ks=(2,), assert_claim=False)
         else:
-            run(emit)
+            run(emit_row)
 
 
 if __name__ == "__main__":

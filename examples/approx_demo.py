@@ -13,9 +13,11 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks.approx_error import fig1_matrix_level  # noqa: E402
+from repro.launch.device import enable_compile_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     print("budget = keep 10% of {MRA block entries | ranks | nonzeros}\n")
     print(f"{'seed':>4} {'MRA':>8} {'SVD(opt)':>9} {'Nystrom':>9} {'sparse*':>8}")
     errs = []

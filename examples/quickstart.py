@@ -1,15 +1,26 @@
 """Quickstart: MRA-2 attention as a drop-in module.
 
-    PYTHONPATH=src python examples/quickstart.py
+    PYTHONPATH=src python examples/quickstart.py              # on a TPU
+    PYTHONPATH=src python examples/quickstart.py --interpret  # elsewhere
 """
+import argparse
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import AttentionSpec, MraConfig, full_attention, mra2_attention, self_attention
+from repro.launch.device import enable_compile_cache, kernel_interpret
 
 
 def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernel step in interpret mode "
+                         "(required off-TPU)")
+    args = ap.parse_args()
+    enable_compile_cache()
+    interpret = kernel_interpret(args.interpret)
     rng = np.random.default_rng(0)
     B, Hq, Hkv, N, D = 2, 8, 2, 1024, 64  # GQA: 8 query heads share 2 KV heads
     q = jnp.asarray(rng.standard_normal((B, Hq, N, D)), jnp.bfloat16)
@@ -38,8 +49,9 @@ def main():
     out2 = self_attention(q, k, v, spec, causal=True)
     print("dispatch (causal mra2):", out2.shape, out2.dtype)
 
-    # 4) the Pallas TPU kernel path, validated in interpret mode on CPU
-    cfg_k = MraConfig(block_size=32, blocks_per_row=4, use_kernel=True, interpret=True)
+    # 4) the Pallas TPU kernel path (interpret mode only when asked for)
+    cfg_k = MraConfig(block_size=32, blocks_per_row=4, use_kernel=True,
+                      interpret=interpret)
     out3 = mra2_attention(q.astype(jnp.float32), k.astype(jnp.float32),
                           v.astype(jnp.float32), cfg_k)
     print("kernel path max |diff| vs jnp path:",

@@ -52,8 +52,10 @@ def main():
                          "DESIGN.md §10)")
     ap.add_argument("--use-kernel", action="store_true",
                     help="route MRA chunk/decode attention through the fused "
-                         "Pallas serving kernel (DESIGN.md §11; interpret "
-                         "mode off-TPU — slow on CPU, same tokens)")
+                         "Pallas serving kernel (DESIGN.md §11)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the --use-kernel serving kernel in interpret "
+                         "mode (required off-TPU; slow, same tokens)")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="export the serving engine's request-lifecycle + "
                          "dispatch trace as Chrome-trace JSONL (load in "
@@ -63,7 +65,11 @@ def main():
                          "snapshot (TTFT/inter-token/queue histograms, "
                          "dispatch counters, occupancy gauges) after the run")
     args = ap.parse_args()
+    from repro.launch.device import enable_compile_cache, kernel_interpret
     from repro.launch.mesh import parse_mesh
+
+    enable_compile_cache()
+    interpret = args.use_kernel and kernel_interpret(args.interpret)
     mesh = parse_mesh(args.mesh)
 
     def dump_telemetry(eng):
@@ -119,8 +125,7 @@ def main():
             cfg.attention, kind=kind, decode_blocks=2),
             attn_shard=mesh is not None,
             attn_use_kernel=use_kernel,
-            attn_interpret=use_kernel
-            and jax.devices()[0].platform != "tpu")
+            attn_interpret=use_kernel and interpret)
         model = get_model(cfg)
         params = init_params(model.param_specs(cfg), jax.random.PRNGKey(0))
         if args.ckpt_dir:

@@ -9,8 +9,6 @@ real hardware.
 """
 import argparse
 
-import jax
-
 from repro.configs.base import ModelConfig, ShapeCfg
 from repro.core.attention import AttentionSpec
 from repro.train import TrainConfig, train
@@ -44,14 +42,20 @@ def main():
                     help="comma-separated attention kinds to train")
     ap.add_argument("--use-kernel", action="store_true",
                     help="route MRA attention through the fused Pallas "
-                         "fwd+bwd kernels (interpret mode off-TPU)")
+                         "fwd+bwd kernels")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the --use-kernel kernels in interpret mode "
+                         "(required off-TPU)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--mesh", default="1",
                     help="device mesh 'D' or 'DxM' (data x model; default 1 = "
                          "single device; attention shards via shard_map)")
     args = ap.parse_args()
-    interpret = jax.devices()[0].platform != "tpu"
+    from repro.launch.device import enable_compile_cache, kernel_interpret
     from repro.launch.mesh import parse_mesh
+
+    enable_compile_cache()
+    interpret = args.use_kernel and kernel_interpret(args.interpret)
     mesh = parse_mesh(args.mesh)
 
     p = PRESETS[args.preset]
@@ -62,7 +66,7 @@ def main():
         tc = TrainConfig(steps=args.steps, lr=1e-3, warmup=20, log_every=20,
                          ckpt_dir=args.ckpt_dir and f"{args.ckpt_dir}/{kind}",
                          use_kernel=args.use_kernel or None,
-                         kernel_interpret=args.use_kernel and interpret,
+                         kernel_interpret=interpret,
                          shard_attention=True if mesh is not None else None)
         hist = []
         print(f"=== training with attention={kind} ===")

@@ -49,7 +49,7 @@ case "${1:-fast}" in
     # context 8x its fine window through the interpret-mode serving kernel,
     # collapsing evicted pages up the hierarchy (asserts per-level occupancy
     # + bounded live window internally)
-    python -m benchmarks.serve_bench --long-ctx-smoke
+    python -m benchmarks.serve_bench --long-ctx-smoke --interpret
     ;;
   lint)
     # tracked bytecode is a repo-hygiene regression (76 .pyc files were once

@@ -1,6 +1,7 @@
-"""qwen3-1.7b — qk_norm, GQA [hf:Qwen/Qwen3-8B; hf].
+"""qwen3-1.7b — qk_norm, GQA, tied embeddings [hf:Qwen/Qwen3-1.7B config.json].
 
-28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936.
+28L d_model=2048 16H (GQA kv=8, head_dim 128) d_ff=6144 vocab=151936,
+rope_theta 1e6, tie_word_embeddings true (1.7B parameters).
 """
 from repro.configs.base import ModelConfig
 from repro.core.attention import AttentionSpec
@@ -19,6 +20,7 @@ CONFIG = ModelConfig(
     head_dim=128,
     qk_norm=True,
     rope_theta=1e6,
+    tie_embeddings=True,
     attention=AttentionSpec(kind="mra2", block_size=128, blocks_per_row=4,
                             decode_blocks=16),
     remat="full",

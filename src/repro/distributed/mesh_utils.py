@@ -8,36 +8,11 @@ what CPU unit tests use.
 from __future__ import annotations
 
 import contextlib
-import inspect
 from typing import Optional
 
 from jax.sharding import Mesh
 
-try:  # jax >= 0.5 exposes shard_map at the top level (mesh keyword-only,
-    # check_rep renamed check_vma)
-    from jax import shard_map as _shard_map
-except ImportError:  # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SM_PARAMS = inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_rep=True):
-    """Version-portable shard_map: one calling convention for every jax.
-
-    Callers use the 0.4.x names (positional-or-keyword ``mesh``,
-    ``check_rep``); this forwards keywords and renames ``check_rep`` to
-    ``check_vma`` on jax versions that made the switch.
-    """
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in _SM_PARAMS:
-        kw["check_vma"] = check_rep
-    elif "check_rep" in _SM_PARAMS:
-        kw["check_rep"] = check_rep
-    return _shard_map(f, **kw)
-
-
-__all__ = ["get_mesh", "use_mesh", "dp_axes", "has_axis", "shard_map"]
+__all__ = ["get_mesh", "use_mesh", "dp_axes", "has_axis"]
 
 _CURRENT: list[Optional[Mesh]] = [None]
 

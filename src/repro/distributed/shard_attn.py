@@ -45,6 +45,7 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 from jax.sharding import PartitionSpec as P
 
 from . import mesh_utils
@@ -109,8 +110,8 @@ def sharded_self_attention(q, k, v, spec, *, causal, key_mask=None):
             key_mask=a.get("km"),
         )
 
-    return mesh_utils.shard_map(
-        body, mesh, in_specs=(in_specs,), out_specs=s4, check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(in_specs,), out_specs=s4, check_vma=False
     )(args)
 
 
@@ -180,8 +181,8 @@ def _sharded_kv_attention(q, k_cache, v_cache, lengths, spec, *, q_pos=None,
         return decode_attention(a["q"], a["k"], a["v"], a["len"], local_spec,
                                 **kw)
 
-    return mesh_utils.shard_map(
-        body, mesh, in_specs=(in_specs,), out_specs=s4, check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(in_specs,), out_specs=s4, check_vma=False
     )(args)
 
 
@@ -238,6 +239,6 @@ def sharded_window_attention(q, k_new, v_new, k_cache, v_cache, kv_pos,
             a["q"], a["kn"], a["vn"], a["kc"], a["vc"], a["pc"], a["pos"],
             a["tv"], window=window, hd=hd)
 
-    return mesh_utils.shard_map(
-        body, mesh, in_specs=(in_specs,), out_specs=s4, check_rep=False
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(in_specs,), out_specs=s4, check_vma=False
     )(args)

@@ -62,10 +62,16 @@ def _block_mask(flags, km, b):
     diag = (flags & 2) == 2
     rows = jax.lax.broadcasted_iota(jnp.int32, (b, b), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (b, b), 1)
-    tri_ok = rows >= cols
-    mask = jnp.where(diag, tri_ok, jnp.ones_like(tri_ok))
-    mask = mask & jnp.broadcast_to(valid, (b, b))
-    return mask & jnp.broadcast_to((km > 0)[None, :], (b, b))
+    # pure boolean algebra: Mosaic has no select over i1 vectors
+    mask = ((rows >= cols) | ~diag) & valid
+    return mask & (km > 0)[None, :]
+
+
+def _rows(x, b):
+    """(R, n) -> (R, n // b, 1, b): one (1, b) row per block. A (1, 1, 1, b)
+    block then has last two dims equal to the array's, as Mosaic's tiling
+    requires of every block."""
+    return x.reshape(x.shape[0], x.shape[1] // b, 1, b)
 
 
 def _dot(a, b_, dims):
@@ -81,8 +87,8 @@ def _recompute_weights(q_ref, k_ref, mt_ref, flags, km_ref, scale, b):
     """
     q = q_ref[0].astype(jnp.float32)
     k = k_ref[0].astype(jnp.float32)
-    s = _dot(q, k, ((1,), (1,))) * scale - mt_ref[0][:, None]
-    mask = _block_mask(flags, km_ref[0], b)
+    s = _dot(q, k, ((1,), (1,))) * scale - mt_ref[0, 0, 0][:, None]
+    mask = _block_mask(flags, km_ref[0, 0, 0], b)
     return jnp.where(mask, jnp.exp(jnp.minimum(s, 0.0)), 0.0), q, k
 
 
@@ -99,11 +105,11 @@ def _fwd_kernel(
     q_ref,  # (1, b, d)
     k_ref,  # (1, b, d)
     v_ref,  # (1, b, d)
-    c_ref,  # (1, 1) stabilizer floor for this query block (coarse bg max)
-    km_ref,  # (1, b) key validity for this key block
+    c_ref,  # (1, 1, 1, 1) stabilizer floor for this query block (coarse bg max)
+    km_ref,  # (1, 1, 1, b) key validity for this key block
     o_ref,  # (1, b, d) accumulated numerator (stabilized by mt)
-    r_ref,  # (1, b) accumulated row sums
-    mt_ref,  # (1, b) running per-token max stabilizer
+    r_ref,  # (1, 1, 1, b) accumulated row sums
+    mt_ref,  # (1, 1, 1, b) running per-token max stabilizer
     *,
     scale: float,
     block_size: int,
@@ -116,17 +122,17 @@ def _fwd_kernel(
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
         r_ref[...] = jnp.zeros_like(r_ref)
-        mt_ref[...] = jnp.zeros_like(mt_ref) + c_ref[0, 0]
+        mt_ref[...] = jnp.zeros_like(mt_ref) + c_ref[0, 0, 0, 0]
 
     q = q_ref[0].astype(jnp.float32)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     s = _dot(q, k, ((1,), (1,))) * scale
-    mask = _block_mask(flags_ref[bhg, i], km_ref[0], b)
+    mask = _block_mask(flags_ref[bhg, i], km_ref[0, 0, 0], b)
 
     # online rescale (flash-attention): raise the running max, shrink the
     # resident accumulators, then add this block at the new stabilizer.
-    m_old = mt_ref[0]
+    m_old = mt_ref[0, 0, 0]
     m_new = jnp.maximum(m_old, jnp.max(jnp.where(mask, s, NEG_INF), axis=1))
     alpha = jnp.exp(m_old - m_new)  # ≤ 1
     # valid entries have s ≤ m_new by construction; the min guards the
@@ -134,8 +140,8 @@ def _fwd_kernel(
     a = jnp.where(mask, jnp.exp(jnp.minimum(s - m_new[:, None], 0.0)), 0.0)
 
     o_ref[0] = o_ref[0] * alpha[:, None] + _dot(a, v, ((1,), (0,)))
-    r_ref[0] = r_ref[0] * alpha + jnp.sum(a, axis=1)
-    mt_ref[0] = m_new
+    r_ref[0, 0, 0] = r_ref[0, 0, 0] * alpha + jnp.sum(a, axis=1)
+    mt_ref[0, 0, 0] = m_new
 
 
 def block_sparse_attention_fwd(
@@ -159,11 +165,13 @@ def block_sparse_attention_fwd(
     m = x_idx.shape[1]
     b = block_size
 
+    nb = n // b
+
     kernel = functools.partial(_fwd_kernel, scale=scale, block_size=b)
     out_shapes = (
         jax.ShapeDtypeStruct((BHG, n, d), jnp.float32),
-        jax.ShapeDtypeStruct((BHG, n), jnp.float32),
-        jax.ShapeDtypeStruct((BHG, n), jnp.float32),
+        jax.ShapeDtypeStruct((BHG, nb, 1, b), jnp.float32),
+        jax.ShapeDtypeStruct((BHG, nb, 1, b), jnp.float32),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -172,13 +180,13 @@ def block_sparse_attention_fwd(
             pl.BlockSpec((1, b, d), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i], 0)),
             pl.BlockSpec((1, b, d), lambda bhg, i, xi, yi, fi, fl: (bhg // group, yi[bhg, i], 0)),
             pl.BlockSpec((1, b, d), lambda bhg, i, xi, yi, fi, fl: (bhg // group, yi[bhg, i], 0)),
-            pl.BlockSpec((1, 1), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i])),
-            pl.BlockSpec((1, b), lambda bhg, i, xi, yi, fi, fl: (bhg // group, yi[bhg, i])),
+            pl.BlockSpec((1, 1, 1, 1), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i], 0, 0)),
+            pl.BlockSpec((1, 1, 1, b), lambda bhg, i, xi, yi, fi, fl: (bhg // group, yi[bhg, i], 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, b, d), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i], 0)),
-            pl.BlockSpec((1, b), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i])),
-            pl.BlockSpec((1, b), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i])),
+            pl.BlockSpec((1, 1, 1, b), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i], 0, 0)),
+            pl.BlockSpec((1, 1, 1, b), lambda bhg, i, xi, yi, fi, fl: (bhg, xi[bhg, i], 0, 0)),
         ],
     )
     out, rowsum, mt = pl.pallas_call(
@@ -186,8 +194,9 @@ def block_sparse_attention_fwd(
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
-    )(x_idx, y_idx, first, flags, q, k, v, c, km)
-    return out, rowsum, mt
+    )(x_idx, y_idx, first, flags, q, k, v, c.reshape(BHG, nb, 1, 1),
+      _rows(km, b))
+    return out, rowsum.reshape(BHG, n), mt.reshape(BHG, n)
 
 
 # --------------------------------------------------------------------------- #
@@ -198,10 +207,10 @@ def _bwd_dq_kernel(
     q_ref,   # (1, b, d)
     k_ref,   # (1, b, d)
     v_ref,   # (1, b, d)
-    mt_ref,  # (1, b) forward per-token stabilizer for this query block
+    mt_ref,  # (1, 1, 1, b) forward per-token stabilizer for this query block
     do_ref,  # (1, b, d) numerator cotangent tile
-    dr_ref,  # (1, b) row-sum cotangent tile
-    km_ref,  # (1, b)
+    dr_ref,  # (1, 1, 1, b) row-sum cotangent tile
+    km_ref,  # (1, 1, 1, b)
     dq_ref,  # (1, b, d) out
     *,
     scale: float,
@@ -221,7 +230,7 @@ def _bwd_dq_kernel(
     v = v_ref[0].astype(jnp.float32)
     # da[i,j] = <do_i, v_j> + dr_i ; ds = a ⊙ da  (softmax-free: the
     # normalization lives outside the kernel contract)
-    ds = a * (_dot(do, v, ((1,), (1,))) + dr_ref[0][:, None])
+    ds = a * (_dot(do, v, ((1,), (1,))) + dr_ref[0, 0, 0][:, None])
     dq_ref[0] += _dot(ds, k, ((1,), (0,))) * scale
 
 
@@ -234,10 +243,10 @@ def _bwd_dkv_kernel(
     q_ref,   # (1, b, d) query block of the owning BHG row
     k_ref,   # (1, b, d)
     v_ref,   # (1, b, d)
-    mt_ref,  # (1, b)
+    mt_ref,  # (1, 1, 1, b)
     do_ref,  # (1, b, d)
-    dr_ref,  # (1, b)
-    km_ref,  # (1, b)
+    dr_ref,  # (1, 1, 1, b)
+    km_ref,  # (1, 1, 1, b)
     dk_ref,  # (1, b, d) out
     dv_ref,  # (1, b, d) out
     *,
@@ -257,7 +266,7 @@ def _bwd_dkv_kernel(
     )
     do = do_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
-    ds = a * (_dot(do, v, ((1,), (1,))) + dr_ref[0][:, None])
+    ds = a * (_dot(do, v, ((1,), (1,))) + dr_ref[0, 0, 0][:, None])
 
     dk_ref[0] += _dot(ds, q, ((0,), (0,))) * scale  # ds^T q
     dv_ref[0] += _dot(a, do, ((0,), (0,)))  # a^T do
@@ -298,6 +307,7 @@ def block_sparse_attention_bwd(
     b = block_size
     M1 = xq.shape[1]
     M2 = xk.shape[1]
+    mt, dr, km = _rows(mt, b), _rows(dr, b), _rows(km, b)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_size=b),
@@ -308,10 +318,10 @@ def block_sparse_attention_bwd(
                 pl.BlockSpec((1, b, d), lambda g, i, xi, yi, fi, fl: (g, xi[g, i], 0)),
                 pl.BlockSpec((1, b, d), lambda g, i, xi, yi, fi, fl: (g // group, yi[g, i], 0)),
                 pl.BlockSpec((1, b, d), lambda g, i, xi, yi, fi, fl: (g // group, yi[g, i], 0)),
-                pl.BlockSpec((1, b), lambda g, i, xi, yi, fi, fl: (g, xi[g, i])),
+                pl.BlockSpec((1, 1, 1, b), lambda g, i, xi, yi, fi, fl: (g, xi[g, i], 0, 0)),
                 pl.BlockSpec((1, b, d), lambda g, i, xi, yi, fi, fl: (g, xi[g, i], 0)),
-                pl.BlockSpec((1, b), lambda g, i, xi, yi, fi, fl: (g, xi[g, i])),
-                pl.BlockSpec((1, b), lambda g, i, xi, yi, fi, fl: (g // group, yi[g, i])),
+                pl.BlockSpec((1, 1, 1, b), lambda g, i, xi, yi, fi, fl: (g, xi[g, i], 0, 0)),
+                pl.BlockSpec((1, 1, 1, b), lambda g, i, xi, yi, fi, fl: (g // group, yi[g, i], 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, b, d), lambda g, i, xi, yi, fi, fl: (g, xi[g, i], 0)),
@@ -330,10 +340,10 @@ def block_sparse_attention_bwd(
                 pl.BlockSpec((1, b, d), lambda kv, i, ro, xi, yi, fi, fl: (ro[kv, i], xi[kv, i], 0)),
                 pl.BlockSpec((1, b, d), lambda kv, i, ro, xi, yi, fi, fl: (kv, yi[kv, i], 0)),
                 pl.BlockSpec((1, b, d), lambda kv, i, ro, xi, yi, fi, fl: (kv, yi[kv, i], 0)),
-                pl.BlockSpec((1, b), lambda kv, i, ro, xi, yi, fi, fl: (ro[kv, i], xi[kv, i])),
+                pl.BlockSpec((1, 1, 1, b), lambda kv, i, ro, xi, yi, fi, fl: (ro[kv, i], xi[kv, i], 0, 0)),
                 pl.BlockSpec((1, b, d), lambda kv, i, ro, xi, yi, fi, fl: (ro[kv, i], xi[kv, i], 0)),
-                pl.BlockSpec((1, b), lambda kv, i, ro, xi, yi, fi, fl: (ro[kv, i], xi[kv, i])),
-                pl.BlockSpec((1, b), lambda kv, i, ro, xi, yi, fi, fl: (kv, yi[kv, i])),
+                pl.BlockSpec((1, 1, 1, b), lambda kv, i, ro, xi, yi, fi, fl: (ro[kv, i], xi[kv, i], 0, 0)),
+                pl.BlockSpec((1, 1, 1, b), lambda kv, i, ro, xi, yi, fi, fl: (kv, yi[kv, i], 0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, b, d), lambda kv, i, ro, xi, yi, fi, fl: (kv, yi[kv, i], 0)),

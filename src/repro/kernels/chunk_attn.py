@@ -23,8 +23,8 @@ background and the final normalization — runs *inside one kernel*:
     ``pl.when``-guarded fetch per page in the selection union, fused with a
     flash-style online softmax (running per-row max, rescaled accumulators)
     and the exact ``pos_k <= q_pos`` mask. No ``(…, m, b, D)`` gather tensor
-    ever reaches HBM. int8 pages are dequantized in VMEM from per-token
-    scale slices.
+    ever reaches HBM. int8 pages apply their per-token scale rows to the
+    page's scores and softmax weights (``s·ks``, ``(a·vs) @ v``).
   * background + normalize — the coarse background
     ``Σ_bg exp(μ − c)·count_y · v̄_y`` is a ``(rows, nb) @ (nb, D)`` matmul
     against the resident ``v_ds`` tile, aligned onto the two-level
@@ -92,28 +92,29 @@ def _dot(a, b_, dims):
 
 def _chunk_kernel(
     # VMEM tiles
-    q_ref,       # (1, G, Ct, D) query tile (fp32)
-    qpos_ref,    # (1, G, Ct, 1) int32 global positions (-1 = padded row)
+    q_ref,       # (1, G, Ct, D) query tile (fp32); (1, Ct, G, D) in latency
+    qpos_ref,    # (1, G, Ct, 1) int32 global positions (-1 = padded row),
+                 # laid out like q_ref
     kds_ref,     # (1, nb, D) per-page K means (coarse scoring keys)
     vds_ref,     # (1, nb, D) per-page V means (coarse background values)
-    counts_ref,  # (1, nb) f32 valid tokens per page
-    pb_ref,      # (1, nb) int32 page table row (logical block, -1 dead)
+    counts_ref,  # (1, 1, nb) f32 valid tokens per page
+    pb_ref,      # (1, 1, nb) int32 page table row (logical block, -1 dead)
     hk_ref,      # (1, NU, D) f32 collapsed-level + tail K means (§14);
                  # (1, 1, D) zero dummy when with_upper is False
     hv_ref,      # (1, NU, D) f32 collapsed-level + tail V means
-    hcnt_ref,    # (1, NU) f32 per-entry token counts (0 = dead entry)
+    hcnt_ref,    # (1, 1, NU) f32 per-entry token counts (0 = dead entry)
     # ANY-space refs (manual DMA sources)
     k_any,       # (BKV, nb, b, D) cache dtype
     v_any,       # (BKV, nb, b, D)
-    ks_any,      # (BKV, nb, b, 1) f32 dequant scales ((1,1,1,1) dummy)
-    vs_any,      # (BKV, nb, b, 1)
+    ks_any,      # (BKV, nb, 1, b) f32 dequant scales ((1,1,1,1) dummy)
+    vs_any,      # (BKV, nb, 1, b)
     # output
-    o_ref,       # (1, G, Ct, D) f32
+    o_ref,       # (1, G, Ct, D) f32, laid out like q_ref
     # scratch
     kpage,       # (b, D) VMEM landing pad for one K page
     vpage,       # (b, D)
-    kspage,      # (b, 1) per-token K scales for the page
-    vspage,      # (b, 1)
+    kspage,      # (1, b) per-token K scales for the page
+    vspage,      # (1, b)
     sems,        # (4,) DMA semaphores
     acc_ref,     # (rows, D) f32 online-softmax numerator
     rs_ref,      # (rows, 1) f32 row sum
@@ -128,15 +129,17 @@ def _chunk_kernel(
 ):
     r = pl.program_id(0)
     b = block_size
-    _, G, Ct, D = q_ref.shape
+    # rows are (G, Ct) or (Ct, G) flattened: every row is an independent
+    # query, so the order only has to agree between q, qpos and the output
+    _, t1, t2, D = q_ref.shape
     nb = kds_ref.shape[1]
-    rows = G * Ct
+    rows = t1 * t2
 
     q = q_ref[0].reshape(rows, D)                 # fp32 query tile
     qp = qpos_ref[0].reshape(rows, 1)             # int32, lane dim kept
     kds = kds_ref[0]                              # (nb, D)
-    pbrow = pb_ref[...]                           # (1, nb)
-    cnt = counts_ref[...]                         # (1, nb)
+    pbrow = pb_ref[0]                             # (1, nb)
+    cnt = counts_ref[0]                           # (1, nb)
 
     # ---- in-kernel coarse scores + causal/validity masks -------------------
     coarse = _dot(q, kds, ((1,), (1,))) * scale   # (rows, nb) — MXU matmul
@@ -187,12 +190,11 @@ def _chunk_kernel(
             cp_v.wait()
             k = kpage[...].astype(jnp.float32)
             vv = vpage[...].astype(jnp.float32)
-            if quant:  # int8 pages: dequantize in VMEM from per-token scales
+            s = _dot(q, k, ((1,), (1,))) * scale          # (rows, b) on MXU
+            if quant:  # int8 pages: per-token scales fold in on the key axis
                 cp_ks.wait()
                 cp_vs.wait()
-                k = k * kspage[...]
-                vv = vv * vspage[...]
-            s = _dot(q, k, ((1,), (1,))) * scale          # (rows, b) on MXU
+                s = s * kspage[...]
             blk = jnp.sum(jnp.where(col1 == j, pbrow, 0))  # logical block id
             pos = blk * b + jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
             selcol = jnp.max(
@@ -207,7 +209,8 @@ def _chunk_kernel(
                                keepdims=True))
             alpha = jnp.exp(m_old - m_new)
             a = jnp.where(ok, jnp.exp(s - m_new), 0.0)
-            acc_ref[...] = acc_ref[...] * alpha + _dot(a, vv, ((1,), (0,)))
+            av = a * vspage[...] if quant else a      # a @ (diag(vs) · v)
+            acc_ref[...] = acc_ref[...] * alpha + _dot(av, vv, ((1,), (0,)))
             rs_ref[...] = rs_ref[...] * alpha + jnp.sum(a, axis=1,
                                                         keepdims=True)
             mt_ref[...] = m_new
@@ -224,7 +227,7 @@ def _chunk_kernel(
         # tokens — liveness is the one gate — and their maxima join the row
         # stabilizer before any exp: far history can dominate the window.
         hmu = _dot(q, hk_ref[0], ((1,), (1,))) * scale   # (rows, NU)
-        hlive = hcnt_ref[...] > 0.0                      # (1, NU)
+        hlive = hcnt_ref[0] > 0.0                        # (1, NU)
         hmu = jnp.where(hlive, hmu, NEG_INF)
         c = jnp.maximum(c, jnp.max(hmu, axis=1, keepdims=True))
     mt = mt_ref[...]
@@ -240,12 +243,12 @@ def _chunk_kernel(
         out = out + adj * _dot(w, vds, ((1,), (0,)))   # (rows, nb)@(nb, D)
         rs = rs + adj * jnp.sum(w, axis=1, keepdims=True)
         if with_upper:
-            wh = jnp.where(hlive, jnp.exp(hmu - c), 0.0) * hcnt_ref[...]
+            wh = jnp.where(hlive, jnp.exp(hmu - c), 0.0) * hcnt_ref[0]
             out = out + adj * _dot(wh, hv_ref[0], ((1,), (0,)))
             rs = rs + adj * jnp.sum(wh, axis=1, keepdims=True)
     alive = rs > 0.0
     o = jnp.where(alive, out, 0.0) / jnp.where(alive, rs, 1.0)
-    o_ref[0] = o.reshape(G, Ct, D)
+    o_ref[0] = o.reshape(t1, t2, D)
 
 
 def _no_grad(*args, **kw):
@@ -257,20 +260,28 @@ def _no_grad(*args, **kw):
 @functools.partial(
     jax.custom_jvp, nondiff_argnums=(13, 14, 15, 16, 17, 18, 19, 20))
 def _chunk_attention_call(
-    q4, qpos4, kds3, vds3, counts2, pb2, hk3, hv3, hcnt2, k4, v4, ks4, vs4,
+    q4, qpos4, kds3, vds3, counts3, pb3, hk3, hv3, hcnt3, k4, v4, ks4, vs4,
     scale, block_size, m, c_tile, quant, include_bg, with_upper, interpret,
 ):
     """pallas_call entry. q4 (BKV, G, Cp, D) fp32; qpos4 (BKV, G, Cp, 1)
-    int32 (−1 = padded row); kds3/vds3 (BKV, nb, D) fp32; counts2/pb2
-    (B, nb); hk3/hv3 (BKV, NU, D) fp32 collapsed-level + tail means with
-    hcnt2 (B, NU) counts when ``with_upper`` (zero (1, 1, D)/(1, 1) dummies
-    otherwise — the fold is statically skipped); k4/v4 (BKV, nb, b, D)
-    cache dtype; ks4/vs4 (BKV, nb, b, 1) fp32 scales ((1, 1, 1, 1) dummies
+    int32 (−1 = padded row) — both (BKV, Cp, G, ·) when ``c_tile == 1``, so
+    a one-query tile is a full (G, ·) slab and the last two block dims are
+    the array's (Mosaic's tiling rule); kds3/vds3 (BKV, nb, D) fp32; counts3/pb3
+    (B, 1, nb); hk3/hv3 (BKV, NU, D) fp32 collapsed-level + tail means with
+    hcnt3 (B, 1, NU) counts when ``with_upper`` (zero (1, 1, D)/(1, 1, 1)
+    dummies otherwise — the fold is statically skipped); k4/v4 (BKV, nb, b, D)
+    cache dtype; ks4/vs4 (BKV, nb, 1, b) fp32 scales ((1, 1, 1, 1) dummies
     when not ``quant``). ``Cp`` must be a multiple of the static query-tile
     width ``c_tile``."""
-    BKV, G, Cp, D = q4.shape
+    c_major = c_tile == 1
+    if c_major:
+        BKV, Cp, G, D = q4.shape
+        q_block, q_index = (1, 1, G), lambda r, t: (r, t, 0, 0)
+    else:
+        BKV, G, Cp, D = q4.shape
+        q_block, q_index = (1, G, c_tile), lambda r, t: (r, 0, t, 0)
     nb, b = k4.shape[1], k4.shape[2]
-    B = counts2.shape[0]
+    B = counts3.shape[0]
     hkv = BKV // B
     rows = G * c_tile
     nu = hk3.shape[1]
@@ -279,23 +290,26 @@ def _chunk_attention_call(
         _chunk_kernel, scale=scale, block_size=b, m=m, quant=quant,
         include_bg=include_bg, with_upper=with_upper)
     grid = (BKV, Cp // c_tile)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    # per-batch side rows are (B, 1, n) with a (1, 1, n) block: the last two
+    # block dims then equal the array's, which Mosaic's tiling requires
+    batch_row = lambda r, t: (r // hkv, 0, 0)  # noqa: E731
     if with_upper:  # resident tiles, one row per (batch·kv-head) like kds
         hmean_spec = pl.BlockSpec((1, nu, D), lambda r, t: (r, 0, 0))
-        hcnt_spec = pl.BlockSpec((1, nu), lambda r, t: (r // hkv, 0))
+        hcnt_spec = pl.BlockSpec((1, 1, nu), batch_row)
     else:  # single shared dummy tile, never read
         hmean_spec = pl.BlockSpec((1, 1, D), lambda r, t: (0, 0, 0))
-        hcnt_spec = pl.BlockSpec((1, 1), lambda r, t: (0, 0))
+        hcnt_spec = pl.BlockSpec((1, 1, 1), lambda r, t: (0, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, G, c_tile, D), lambda r, t: (r, 0, t, 0)),
-            pl.BlockSpec((1, G, c_tile, 1), lambda r, t: (r, 0, t, 0)),
+            pl.BlockSpec((*q_block, D), q_index),
+            pl.BlockSpec((*q_block, 1), q_index),
             pl.BlockSpec((1, nb, D), lambda r, t: (r, 0, 0)),
             pl.BlockSpec((1, nb, D), lambda r, t: (r, 0, 0)),
-            pl.BlockSpec((1, nb), lambda r, t: (r // hkv, 0)),
-            pl.BlockSpec((1, nb), lambda r, t: (r // hkv, 0)),
+            pl.BlockSpec((1, 1, nb), batch_row),
+            pl.BlockSpec((1, 1, nb), batch_row),
             hmean_spec,
             hmean_spec,
             hcnt_spec,
@@ -304,25 +318,25 @@ def _chunk_attention_call(
             any_spec,
             any_spec,
         ],
-        out_specs=pl.BlockSpec((1, G, c_tile, D), lambda r, t: (r, 0, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((BKV, G, Cp, D), jnp.float32),
+        out_specs=pl.BlockSpec((*q_block, D), q_index),
+        out_shape=jax.ShapeDtypeStruct(q4.shape, jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((b, D), k4.dtype),
             pltpu.VMEM((b, D), v4.dtype),
-            pltpu.VMEM((b, 1), jnp.float32),
-            pltpu.VMEM((b, 1), jnp.float32),
+            pltpu.VMEM((1, b), jnp.float32),
+            pltpu.VMEM((1, b), jnp.float32),
             pltpu.SemaphoreType.DMA((4,)),
             pltpu.VMEM((rows, D), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # grid steps are fully independent (no cross-step accumulators),
             # so the (batch·kv-head) axis may run on both megacore cores; the
             # chunk-tile axis stays sequential to keep kds/vds tiles resident
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q4, qpos4, kds3, vds3, counts2, pb2, hk3, hv3, hcnt2, k4, v4, ks4, vs4)
+    )(q4, qpos4, kds3, vds3, counts3, pb3, hk3, hv3, hcnt3, k4, v4, ks4, vs4)
     return out
 
 
@@ -378,34 +392,38 @@ def chunk_attention_kernel(
         q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, pad), (0, 0)))
         qpos4 = jnp.pad(qpos4, ((0, 0), (0, 0), (0, pad), (0, 0)),
                         constant_values=-1)
+    if c_tile == 1:  # single-query tiles take the (BKV, Cp, G, ·) layout
+        q4, qpos4 = q4.swapaxes(1, 2), qpos4.swapaxes(1, 2)
 
     k4 = k_cache.reshape(BKV, nb, b, *k_cache.shape[3:])
     v4 = v_cache.reshape(BKV, nb, b, *v_cache.shape[3:])
     quant = k_scale is not None
     if quant:
-        ks4 = k_scale.astype(jnp.float32).reshape(BKV, nb, b)[..., None]
-        vs4 = v_scale.astype(jnp.float32).reshape(BKV, nb, b)[..., None]
+        ks4 = k_scale.astype(jnp.float32).reshape(BKV, nb, 1, b)
+        vs4 = v_scale.astype(jnp.float32).reshape(BKV, nb, 1, b)
     else:  # dummy tiles keep the arity static; never DMA'd (static skip)
         ks4 = jnp.zeros((1, 1, 1, 1), jnp.float32)
         vs4 = ks4
     kds3 = pre.k_ds.astype(jnp.float32).reshape(BKV, nb, D)
     vds3 = pre.v_ds.astype(jnp.float32).reshape(BKV, nb, D)
-    counts2 = pre.counts.astype(jnp.float32)
-    pb2 = pre.pb.astype(jnp.int32)
+    counts3 = pre.counts.astype(jnp.float32)[:, None]
+    pb3 = pre.pb.astype(jnp.int32)[:, None]
     with_upper = pre.upper is not None
     if with_upper:  # H-level hierarchy (§14): levels + tail as resident tiles
         nu = pre.upper.k_mean.shape[2]
         hk3 = pre.upper.k_mean.astype(jnp.float32).reshape(BKV, nu, D)
         hv3 = pre.upper.v_mean.astype(jnp.float32).reshape(BKV, nu, D)
-        hcnt2 = pre.upper.counts.astype(jnp.float32)
+        hcnt3 = pre.upper.counts.astype(jnp.float32)[:, None]
     else:  # dummy tiles keep the arity static; the fold is compiled out
         hk3 = jnp.zeros((1, 1, D), jnp.float32)
         hv3 = hk3
-        hcnt2 = jnp.zeros((1, 1), jnp.float32)
+        hcnt3 = jnp.zeros((1, 1, 1), jnp.float32)
 
     out = _chunk_attention_call(
-        q4, qpos4, kds3, vds3, counts2, pb2, hk3, hv3, hcnt2, k4, v4, ks4,
+        q4, qpos4, kds3, vds3, counts3, pb3, hk3, hv3, hcnt3, k4, v4, ks4,
         vs4, pre.scale, b, m, c_tile, quant, include_bg, with_upper,
         interpret,
     )
+    if c_tile == 1:
+        out = out.swapaxes(1, 2)
     return out[:, :, :C].reshape(B, Hkv * G, C, D)

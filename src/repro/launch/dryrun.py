@@ -33,6 +33,7 @@ from repro.optim import AdamW, cosine_schedule
 from repro.train import TrainConfig, make_train_step
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "results", "dryrun")
+TARGET_DEVICE_KIND = "TPU v5 lite"  # jax's device_kind for a TPU v5e chip
 
 _COLL_RE = re.compile(
     r"%?([\w.-]+)\s*=\s*(\S+)\s+"
@@ -158,6 +159,8 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool, do_compile: bool 
         "arch": arch, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16",
         "chips": 512 if multi_pod else 256,
+        # the chip the production meshes model (the roofline's peak table key)
+        "device_kind": TARGET_DEVICE_KIND,
         "kind": shape.kind,
         "attention": cfg.attention.kind,
     }

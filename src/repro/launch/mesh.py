@@ -8,15 +8,10 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 accepts axis_types; 0.4.x does not. All axes here are Auto
-    # (the default on every version), so omitting the kwarg is equivalent.
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    except (TypeError, AttributeError):
-        return jax.make_mesh(shape, axes)
+    # Auto axes: shardings propagate through jit and shard_map opens the
+    # manual regions (DESIGN.md §8)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -3,9 +3,12 @@
 Terms per (arch x shape x mesh) cell, all per-device (the dry-run records
 per-device HLO stats from the SPMD-partitioned module):
 
-    compute term    = HLO_FLOPs / peak_FLOPs            (197 TFLOP/s bf16, v5e)
-    memory term     = HLO_bytes / HBM_bw                (819 GB/s)
-    collective term = collective_bytes / link_bw        (~50 GB/s/link ICI)
+    compute term    = HLO_FLOPs / peak_FLOPs
+    memory term     = HLO_bytes / HBM_bw
+    collective term = collective_bytes / link_bw
+
+with the peaks of the cell's ``device_kind`` from ``PEAKS`` (a kind that is
+not in the table is an error, never a default).
 
 plus MODEL_FLOPS = 6*N*D (train) / 2*N*D (inference; N active for MoE) and the
 useful-compute ratio MODEL_FLOPS / HLO_FLOPs. For scanned train cells the
@@ -23,9 +26,21 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s
-LINK_BW = 50e9  # B/s per ICI link
+# per-chip peaks keyed by jax's ``device_kind``. TPU v5e: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# inter-chip interconnect ~ 4 links of ~50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak FLOP/s, HBM B/s and per-link B/s of one chip of ``device_kind``."""
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no peak table for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "results", "dryrun")
 
@@ -42,19 +57,21 @@ def analyze(cell: dict) -> dict | None:
         return None
     cost = cell.get("cost_extrapolated") or cell.get("cost") or {}
     coll = cell.get("collectives_extrapolated") or cell.get("collectives") or {}
+    pk = peaks(cell.get("device_kind"))
+    peak_flops = pk["flops"]
     flops = cost.get("flops_per_device", 0.0)
     bts = cost.get("bytes_accessed_per_device", 0.0)
     coll_b = sum(v for k, v in coll.items() if k != "count")
-    t_c = flops / PEAK_FLOPS
-    t_m = bts / HBM_BW
-    t_l = coll_b / LINK_BW
+    t_c = flops / peak_flops
+    t_m = bts / pk["hbm_bw"]
+    t_l = coll_b / pk["link_bw"]
     # Analytic memory FLOOR: every input byte read + output byte written once
     # (params/opt-state/KV-cache traffic). The XLA "bytes accessed" figure is
     # an UNFUSED upper bound from the CPU backend — fusion on TPU collapses
     # most intermediate traffic, so the truth lies between floor and bound.
     mem = cell.get("memory", {})
     floor_b = mem.get("argument_bytes", 0) + mem.get("output_bytes", 0)
-    t_m_floor = floor_b / HBM_BW
+    t_m_floor = floor_b / pk["hbm_bw"]
     dom = max((t_c, "compute"), (t_m, "memory"), (t_l, "collective"))[1]
     dom_floor = max((t_c, "compute"), (t_m_floor, "memory"), (t_l, "collective"))[1]
     chips = cell.get("chips", 256)
@@ -75,11 +92,11 @@ def analyze(cell: dict) -> dict | None:
         "mem_gib_per_device": cell.get("memory", {}).get("total_per_device_bytes", 0) / 2**30,
         "fits_16g": cell.get("memory", {}).get("total_per_device_bytes", 0) < 16 * 2**30,
         # roofline fraction: useful compute time / total modeled time (no overlap)
-        "roofline_fraction": (useful / PEAK_FLOPS) / max(t_c + t_m + t_l, 1e-30),
+        "roofline_fraction": (useful / peak_flops) / max(t_c + t_m + t_l, 1e-30),
         # with perfect compute/comm overlap the bound is the max term instead
-        "roofline_fraction_overlap": (useful / PEAK_FLOPS) / max(t_c, t_m, t_l, 1e-30),
+        "roofline_fraction_overlap": (useful / peak_flops) / max(t_c, t_m, t_l, 1e-30),
         # floor accounting: memory term from the analytic floor (TPU-fused view)
-        "roofline_fraction_floor": (useful / PEAK_FLOPS)
+        "roofline_fraction_floor": (useful / peak_flops)
         / max(t_c, t_m_floor, t_l, 1e-30),
     }
     return out

@@ -161,7 +161,7 @@ def moe_block(x, p, cfg: ModelConfig):
             out, aux = local(xl, wr, wi, wg, wo, e0=0, e_local=E)
             return _finish(out, aux)
 
-        return mesh_utils.shard_map(
+        return jax.shard_map(
             tp_body,
             mesh=mesh,
             in_specs=(P(bspec, None, None), P(), P(None, None, "model"),
@@ -196,7 +196,7 @@ def moe_block(x, p, cfg: ModelConfig):
                 aux = jax.tree.map(lambda a: jax.lax.pmean(a, ("model",)), aux)
             return out.reshape(xl.shape), aux
 
-        return mesh_utils.shard_map(
+        return jax.shard_map(
             a2a_body,
             mesh=mesh,
             in_specs=(P(bspec, "model", None), P(), P("model", None, None),
@@ -211,7 +211,7 @@ def moe_block(x, p, cfg: ModelConfig):
         out, aux = local(xl, wr, wi, wg, wo, e0=e0, e_local=e_local)
         return _finish(out, aux)
 
-    return mesh_utils.shard_map(
+    return jax.shard_map(
         ep_body,
         mesh=mesh,
         in_specs=(P(bspec, None, None), P(), P("model", None, None),
