@@ -302,6 +302,8 @@ def _chunk_attention_call(
         hcnt_spec = pl.BlockSpec((1, 1, 1), lambda r, t: (0, 0, 0))
     out = pl.pallas_call(
         kernel,
+        # a stable name per tile mode, found beside tpu_custom_call in the HLO
+        name="mra_chunk_attn_latency" if c_major else "mra_chunk_attn_throughput",
         grid=grid,
         in_specs=[
             pl.BlockSpec((*q_block, D), q_index),

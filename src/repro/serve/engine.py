@@ -34,12 +34,14 @@ attention through shard_map when ``cfg.attn_shard``).
 
 Observability (serve/telemetry.py, DESIGN.md §13): every engine owns a
 ``Telemetry`` whose metric set is declared in ``reset_stats`` — typed
-counters for dispatches/tokens, bounded histograms for prefill-chunk /
-decode-step / draft / verify wall time and the request-derived TTFT /
-queue-wait / inter-token latencies, gauges for scheduler slot occupancy and
-cache page/eviction occupancy, and a Chrome-trace request lifecycle.
-``Engine.stats`` survives as a typed view over the registry (undeclared
-keys raise). ``EngineConfig(telemetry=False)`` is the pinned no-op path.
+counters for dispatches/tokens, bounded histograms for decode-step wall time
+and the request-derived TTFT / queue-wait / inter-token latencies, gauges
+for scheduler slot occupancy and cache page/eviction occupancy, and a
+Chrome-trace request lifecycle. Each phase of ``_iterate`` runs inside a
+``serve.<phase>`` span (``Telemetry.span``); every blocking device read the
+loop makes sits in a ``serve.<phase>.wait`` child. ``Engine.stats``
+survives as a typed view over the registry (undeclared keys raise).
+``EngineConfig(telemetry=False)`` is the pinned no-op path.
 """
 from __future__ import annotations
 
@@ -271,13 +273,12 @@ class Engine:
             "spec_rounds", "draft_dispatches", "verify_dispatches",
             "spec_drafted_tokens", "spec_accepted_tokens",
             "spec_emitted_tokens")
-        # dispatch wall time + request-derived latencies; bounded reservoirs
-        # (a long-lived engine must not grow host memory per step)
+        # decode-wave wall time (it ends in a blocking read) + request-derived
+        # latencies; bounded reservoirs (a long-lived engine must not grow
+        # host memory per step)
         m.declare_histogram(
-            "decode_step_seconds", "prefill_chunk_seconds", "draft_seconds",
-            "verify_seconds", "ttft_seconds", "queue_wait_seconds",
-            "prefill_seconds", "inter_token_seconds",
-            "spec_accepted_per_round")
+            "decode_step_seconds", "ttft_seconds", "queue_wait_seconds",
+            "prefill_seconds", "inter_token_seconds")
         # occupancy gauges, refreshed once per engine iteration; the cache
         # keys come from the backend itself (set_occupancy prefixes them
         # with "cache_") so backends with extra gauges — e.g. the H-level
@@ -315,75 +316,79 @@ class Engine:
     # ------------------------------------------------------------------ #
     def _iterate(self, sched: Scheduler) -> None:
         tel = self.telemetry
-        newly = sched.admit()
-        if newly:
-            mask = np.zeros((self.slots,), bool)
-            mask[newly] = True
-            self.kv.reset_slots(mask)
+        with tel.span("iterate"):
+            with tel.span("admit"):
+                newly = sched.admit()
+                if newly:
+                    mask = np.zeros((self.slots,), bool)
+                    mask[newly] = True
+                    self.kv.reset_slots(mask)
 
-        plan = sched.prefill_plan()
-        if plan is not None:
-            tokens, num_valid, finishing = plan
-            # satellite of §13: prefill dispatches are timed like decode
-            # steps, so TTFT decomposes into queue + prefill + first-decode
-            with tel.dispatch("prefill_chunk", hist="prefill_chunk_seconds",
-                              tokens=int(num_valid.sum())):
-                logits, self.kv.tree = self._prefill(
-                    self.params, self.kv.tree, jnp.asarray(tokens),
-                    jnp.asarray(num_valid))
-                if finishing:
+            plan = sched.prefill_plan()
+            if plan is not None:
+                self._prefill_chunk(sched, *plan)
+
+            active = sched.decode_mask()
+            if active.any():
+                t0 = tel.now() if tel.enabled else 0.0
+                if self._spec is not None:
+                    active = self._spec.round(self, sched, active)
+                if active.any():
+                    self._plain_decode(sched, active)
+                if tel.enabled:
+                    tel.metrics.observe("decode_step_seconds", tel.now() - t0)
+            if tel.enabled:
+                with tel.span("occupancy"):
+                    states = [s.state for s in sched.slots]
+                    with tel.span("occupancy.wait"):
+                        occ = self.kv.occupancy()
+                    tel.set_occupancy(
+                        {"queue_depth": len(sched.pending),
+                         "slots_free": states.count(SlotState.FREE),
+                         "slots_prefill": states.count(SlotState.PREFILL),
+                         "slots_decode": states.count(SlotState.DECODE)},
+                        occ)
+
+    def _prefill_chunk(self, sched: Scheduler, tokens: np.ndarray,
+                       num_valid: np.ndarray, finishing: List[int]) -> None:
+        """One jitted prefill_chunk dispatch for every PREFILL slot; slots
+        whose prompt completes sample their first token from it."""
+        tel = self.telemetry
+        with tel.span("prefill_chunk", tokens=int(num_valid.sum())):
+            with tel.span("prefill_chunk.args"):
+                args = jnp.asarray(tokens), jnp.asarray(num_valid)
+            with tel.span("prefill_chunk.launch"):
+                logits, self.kv.tree = self._prefill(self.params, self.kv.tree,
+                                                     *args)
+            if finishing:
+                with tel.span("prefill_chunk.sample"):
                     first = self._sample(
                         logits, jnp.asarray(sched.any_sampling(finishing)),
                         *map(jnp.asarray, sched.sampler_arrays()))
+                with tel.span("prefill_chunk.wait"):
                     first = np.asarray(first)
             tel.metrics.inc("prefill_dispatches")
             tel.metrics.inc("prefill_tokens", int(num_valid.sum()))
-            if finishing:
-                for s in finishing:
-                    tel.on_prefill_done(sched.slots[s].req)
-                    sched.on_sampled(s, first[s])
-                tel.metrics.inc("generated_tokens", len(finishing))
-
-        active = sched.decode_mask()
-        if active.any():
-            t0 = tel.now() if tel.enabled else 0.0
-            if self._spec is not None:
-                # slots whose round window straddles a ring-eviction boundary
-                # take a plain decode step instead (a chunked verify would
-                # evict a block that its earlier queries must still see; the
-                # oracle evicts it only when the boundary token is written) —
-                # up to spec_k waves approaching each block crossing.
-                spec_wave, plain_wave = self._spec.split_wave(self.kv, active)
-                if spec_wave.any():
-                    self._spec.round(self, sched, spec_wave)
-                if plain_wave.any():
-                    self._plain_decode(sched, plain_wave)
-            else:
-                self._plain_decode(sched, active)
-            if tel.enabled:
-                tel.metrics.observe("decode_step_seconds", tel.now() - t0)
-        if tel.enabled:
-            states = [s.state for s in sched.slots]
-            tel.set_occupancy(
-                {"queue_depth": len(sched.pending),
-                 "slots_free": states.count(SlotState.FREE),
-                 "slots_prefill": states.count(SlotState.PREFILL),
-                 "slots_decode": states.count(SlotState.DECODE)},
-                self.kv.occupancy())
+            for s in finishing:
+                tel.on_prefill_done(sched.slots[s].req)
+                sched.on_sampled(s, first[s])
+            tel.metrics.inc("generated_tokens", len(finishing))
 
     def _plain_decode(self, sched: Scheduler, active: np.ndarray) -> None:
         """One fused decode_step + sample dispatch for the ``active`` slots."""
-        feed = sched.feed_tokens()
-        temp, top_k, top_p, seed, step = sched.sampler_arrays()
-        with self.telemetry.dispatch("decode_step",
-                                     slots=int(active.sum())):
-            nxt, self.kv.tree = self._decode(
-                self.params, self.kv.tree, jnp.asarray(feed),
-                jnp.asarray(active), jnp.asarray(sched.any_sampling()),
-                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-                jnp.asarray(seed), jnp.asarray(step))
-            nxt = np.asarray(nxt)
-        self.telemetry.metrics.inc("decode_dispatches")
-        for s in np.flatnonzero(active):
-            sched.on_sampled(int(s), nxt[s])
-        self.telemetry.metrics.inc("generated_tokens", int(active.sum()))
+        tel = self.telemetry
+        with tel.span("decode_step", slots=int(active.sum())):
+            with tel.span("decode_step.args"):
+                temp, top_k, top_p, seed, step = sched.sampler_arrays()
+                args = [jnp.asarray(a) for a in (
+                    sched.feed_tokens(), active, sched.any_sampling(), temp,
+                    top_k, top_p, seed, step)]
+            with tel.span("decode_step.launch"):
+                nxt, self.kv.tree = self._decode(self.params, self.kv.tree,
+                                                 *args)
+            with tel.span("decode_step.wait"):
+                nxt = np.asarray(nxt)
+            tel.metrics.inc("decode_dispatches")
+            for s in np.flatnonzero(active):
+                sched.on_sampled(int(s), nxt[s])
+            tel.metrics.inc("generated_tokens", int(active.sum()))

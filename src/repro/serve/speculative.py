@@ -143,12 +143,24 @@ class SpecDecoder:
         unsafe = (last_boundary > L0) & (last_boundary >= kv.window_tokens)
         return active & ~unsafe, active & unsafe
 
-    def round(self, engine, sched, active: np.ndarray) -> None:
-        """One batched draft(K) -> rewind -> verify -> accept -> trim round.
+    def round(self, engine, sched, active: np.ndarray) -> np.ndarray:
+        """One speculative round over the decode wave ``active``.
 
-        ``active`` is the decode wave mask; inactive slots' state is
-        preserved bit-for-bit through every dispatch.
+        Slots whose round window straddles a ring-eviction boundary (see
+        ``split_wave``) are left out and returned, for the engine's plain
+        decode step; the rest run one draft/verify round. Inactive slots'
+        state is preserved bit-for-bit through every dispatch.
         """
+        tel = engine.telemetry
+        with tel.span("spec_round", k=self.k):
+            with tel.span("spec_round.wait"):  # split_wave reads the lengths
+                wave, plain = self.split_wave(engine.kv, active)
+            if wave.any():
+                self._round(engine, sched, wave)
+        return plain
+
+    def _round(self, engine, sched, active: np.ndarray) -> None:
+        """One batched draft(K) -> rewind -> verify -> accept -> trim round."""
         K = self.k
         kv = engine.kv
         tel = engine.telemetry
@@ -161,7 +173,7 @@ class SpecDecoder:
 
         tok, drafts, qs = fed, [], []
         for j in range(K):
-            with tel.dispatch("draft", hist="draft_seconds", step=j):
+            with tel.span("draft", step=j):
                 tok, q, kv.tree = self._draft(
                     engine.params, kv.tree, tok, act, any_s, temp, top_k,
                     top_p, seed, step0 + j)
@@ -173,7 +185,7 @@ class SpecDecoder:
 
         chunk = jnp.stack([fed] + drafts, axis=1)  # (B, K+1)
         num_valid = jnp.where(act, K + 1, 0).astype(jnp.int32)
-        with tel.dispatch("verify", hist="verify_seconds", k=K):
+        with tel.span("verify", k=K):
             logits, kv.tree, chunk_kv = self._verify(
                 engine.params, kv.tree, chunk, num_valid)
         tel.metrics.inc("verify_dispatches")
@@ -185,7 +197,8 @@ class SpecDecoder:
         # last emitted token is never fed, so the kept stream is L0 + n_out
         kv.spec_rewind(snap, snap["lengths"] + n_out, act, chunk_kv)
 
-        out, n_out, n_acc = map(np.asarray, (out, n_out, n_acc))
+        with tel.span("spec_round.wait"):
+            out, n_out, n_acc = map(np.asarray, (out, n_out, n_acc))
         emitted = 0
         for s in np.flatnonzero(active):
             emitted += sched.on_spec_tokens(

@@ -20,12 +20,16 @@ The observability layer the adaptive-resolution arc reads from (DESIGN.md
     (exportable as JSONL for chrome://tracing / Perfetto; one event object
     per line).
 
-  * **Per-dispatch profiling hooks** — ``Telemetry.dispatch`` wraps every
-    jitted entry (prefill_chunk, decode_step, draft, verify) in a
-    wall-clock span + ``jax.profiler.TraceAnnotation`` tagged with kernel
-    mode and cache family, so device profiles and the host trace line up.
-    The jitted functions themselves carry ``jax.named_scope`` annotations
-    (serve/engine.py) at zero runtime cost.
+  * **Engine-loop spans** — ``Telemetry.span`` opens one span per phase
+    of ``Engine._iterate`` (admission, prefill, decode, speculation,
+    occupancy): a ``jax.profiler.TraceAnnotation("serve.<name>")`` on the
+    profiler's clock plus the same-named event on the tracer's engine lane,
+    tagged with kernel mode and cache family. A span whose name ends in
+    ``.wait`` marks time the host spends blocked on the device. The tracer
+    stamps with one process-wide monotonic clock, so one constant offset
+    maps its spans onto their profiler copies. The jitted functions
+    themselves carry ``jax.named_scope`` annotations (serve/engine.py) at
+    zero runtime cost.
 
 Everything is gated on ``Telemetry.enabled``: disabled, the span/stamp/
 gauge paths are no-ops (``EngineConfig(telemetry=False)``) — only the
@@ -345,14 +349,15 @@ class RequestTrace:
 
 
 class Tracer:
-    """Bounded Chrome-trace event buffer on a monotonic session clock."""
+    """Bounded Chrome-trace event buffer on the process's monotonic clock
+    (``time.perf_counter``, shared by every tracer: no per-instance origin)."""
 
     def __init__(self, max_events: int = 65536):
-        self._t0 = time.perf_counter()
         self.events: collections.deque = collections.deque(maxlen=max_events)
 
-    def now(self) -> float:
-        return time.perf_counter() - self._t0
+    @staticmethod
+    def now() -> float:
+        return time.perf_counter()
 
     def event(self, ph: str, name: str, ts: float, tid: int,
               args: Optional[dict] = None) -> None:
@@ -382,7 +387,7 @@ class Tracer:
         """Events sorted by timestamp + thread-name metadata (valid Chrome
         trace when wrapped in a JSON array; Perfetto loads it directly)."""
         evs = sorted(self.events, key=lambda e: (e["ts"], e["ph"] != "E"))
-        names = {Telemetry.ENGINE_TID: "engine dispatches"}
+        names = {Telemetry.ENGINE_TID: "engine loop"}
         meta = [{"ph": "M", "name": "thread_name", "pid": 0, "tid": tid,
                  "args": {"name": names.get(tid, f"slot {tid}")}}
                 for tid in sorted({e["tid"] for e in evs})]
@@ -442,7 +447,7 @@ def validate_chrome_events(events: List[dict]) -> None:
 # the owner object
 # --------------------------------------------------------------------------- #
 class Telemetry:
-    """One per Engine: registry + tracer + the lifecycle/dispatch helpers.
+    """One per Engine: registry + tracer + the lifecycle/span helpers.
 
     ``enabled=False`` is the no-op fast path: lifecycle stamps, histogram
     observations, gauges, and trace events all short-circuit; counters
@@ -450,7 +455,7 @@ class Telemetry:
     bookkeeping (and integer adds are far below the overhead budget).
     """
 
-    ENGINE_TID = 1000  # trace lane for engine-level dispatch spans
+    ENGINE_TID = 1000  # trace lane for the engine-loop spans
 
     def __init__(self, enabled: bool = True, tags: Optional[dict] = None):
         self.enabled = enabled
@@ -461,27 +466,27 @@ class Telemetry:
     def now(self) -> float:
         return self.trace.now()
 
-    # ---- per-dispatch profiling hooks -------------------------------------- #
+    # ---- engine-loop spans --------------------------------------------------- #
     @contextlib.contextmanager
-    def dispatch(self, name: str, hist: Optional[str] = None, **args):
-        """Span one jitted dispatch: wall clock + profiler annotation.
+    def span(self, name: str, **args):
+        """Span one phase of the engine loop: ``serve.<name>``.
 
-        ``hist`` names a declared histogram to observe the duration into;
-        the trace span lands on the engine lane tagged with the telemetry's
-        static tags (kernel mode, cache family) + ``args``.
+        A ``jax.profiler.TraceAnnotation`` on the profiler's clock plus a
+        tracer span of the same name on the engine lane, tagged with the
+        telemetry's static tags (kernel mode, cache family) + ``args``.
+        Spans nest; a name ending in ``.wait`` marks a blocking device read
+        the engine makes anyway (a span never adds a read or a sync).
         """
         if not self.enabled:
             yield
             return
         import jax  # deferred so metric-only users never pay the import
 
+        name = f"serve.{name}"
         t0 = self.now()
-        with jax.profiler.TraceAnnotation(f"serve.{name}"):
+        with jax.profiler.TraceAnnotation(name):
             yield
-        t1 = self.now()
-        if hist is not None:
-            self.metrics.observe(hist, t1 - t0)
-        self.trace.span(name, t0, t1, self.ENGINE_TID,
+        self.trace.span(name, t0, self.now(), self.ENGINE_TID,
                         {**self.tags, **args} or None)
 
     # ---- request lifecycle -------------------------------------------------- #
@@ -520,7 +525,6 @@ class Telemetry:
     def on_spec_accept(self, req, slot: int, n_accepted: int) -> None:
         if not self.enabled:
             return
-        self.metrics.observe("spec_accepted_per_round", n_accepted)
         self.metrics.append("spec_accept_by_slot", slot, n_accepted)
         if req.trace:
             req.trace.spec_accepts.append(int(n_accepted))
@@ -606,7 +610,7 @@ def _selftest() -> None:
     t0 = tel.now()
     t.instant("submit", t0, 0)
     t.span("request", t0, t0 + 0.02, 0, {"prompt_tokens": 4})
-    t.span("prefill_chunk", t0 + 0.001, t0 + 0.01, Telemetry.ENGINE_TID)
+    t.span("serve.prefill_chunk", t0 + 0.001, t0 + 0.01, Telemetry.ENGINE_TID)
     validate_chrome_events(t.chrome_events())
 
     import tempfile

@@ -19,7 +19,10 @@ Pins the observability contract of the serving stack:
   * occupancy — the uniform cache occupancy keys are populated for all
     three cache families (ring-paged, recurrent-state, hybrid-window).
 """
+import contextlib
+import glob
 import json
+import os
 
 import jax
 import numpy as np
@@ -29,6 +32,9 @@ from repro.configs import get_smoke_config
 from repro.models import get_model, init_params
 from repro.serve import (Engine, EngineConfig, Request, SamplingParams,
                          UndeclaredMetric)
+from repro.serve import engine as engine_mod
+from repro.serve import speculative
+from repro.serve.cache import paged, protocol
 from repro.serve.telemetry import (MetricsRegistry, StatsView,
                                    validate_chrome_events)
 
@@ -59,6 +65,15 @@ def _engine(cfg, params, **kw):
     base = dict(slots=2, max_len=64, chunk=8)
     base.update(kw)
     return Engine(cfg, params, EngineConfig(**base))
+
+
+def _tracer_spans(tel):
+    """(name, begin s, end s) of the engine-lane spans, in recording order
+    (``Tracer.span`` appends each begin event right before its end)."""
+    evs = list(tel.trace.events)
+    return [(b["name"], b["ts"] * 1e-6, e["ts"] * 1e-6)
+            for b, e in zip(evs, evs[1:])
+            if b["ph"] == "B" and b["tid"] == tel.ENGINE_TID]
 
 
 # --------------------------------------------------------------------------- #
@@ -134,8 +149,7 @@ def test_snapshot_json_roundtrip_and_prometheus(cfg, params):
     assert snap["tags"]["family"] == cfg.family
     assert snap["counters"]["requests_completed"] == 4
     for name in ("ttft_seconds", "inter_token_seconds", "queue_wait_seconds",
-                 "prefill_seconds", "decode_step_seconds",
-                 "prefill_chunk_seconds"):
+                 "prefill_seconds", "decode_step_seconds"):
         h = snap["histograms"][name]
         assert set(h) == {"count", "sum", "mean", "p50", "p90", "p99", "max"}
     assert snap["histograms"]["ttft_seconds"]["count"] == 4
@@ -147,14 +161,16 @@ def test_snapshot_json_roundtrip_and_prometheus(cfg, params):
 
 
 def test_prefill_dispatches_are_timed(cfg, params):
-    """Satellite of §13: prefill is timed like decode, so TTFT decomposes
-    into queue + prefill + first-decode with nothing unaccounted."""
+    """Each prefill dispatch opens one ``serve.prefill_chunk`` span holding
+    one ``serve.prefill_chunk.launch`` child, on the engine lane."""
     eng = _engine(cfg, params)
     eng.run(_requests())
-    snap = eng.telemetry.snapshot()
-    h = snap["histograms"]["prefill_chunk_seconds"]
-    assert h["count"] == eng.stats["prefill_dispatches"] > 0
-    assert h["sum"] > 0
+    spans = _tracer_spans(eng.telemetry)
+    outer = [sp for sp in spans if sp[0] == "serve.prefill_chunk"]
+    launch = [sp for sp in spans if sp[0] == "serve.prefill_chunk.launch"]
+    assert len(outer) == len(launch) == eng.stats["prefill_dispatches"] > 0
+    for (_, a0, a1), (_, b0, b1) in zip(outer, launch):
+        assert a0 <= b0 <= b1 <= a1
 
 
 # --------------------------------------------------------------------------- #
@@ -179,11 +195,16 @@ def test_batched_equals_solo_under_telemetry(cfg, params):
         np.testing.assert_array_equal(solo.out, by[len(solo.prompt)])
 
 
-def test_disabled_path_is_noop(cfg, params):
+def test_disabled_path_is_noop(cfg, params, monkeypatch):
     """telemetry=False: counters keep counting (engine bookkeeping), but no
-    stamps, no reservoir growth, no gauges, no trace events."""
+    stamps, no reservoir growth, no gauges, no trace events, and no span
+    reaches the profiler."""
+    opened = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name, **kw: opened.append(name))
     eng = _engine(cfg, params, telemetry=False)
     done = eng.run(_requests())
+    assert opened == []
     assert eng.stats["requests_completed"] == 4
     assert eng.stats["generated_tokens"] > 0
     assert eng.stats["decode_step_seconds"] == []
@@ -225,14 +246,14 @@ def test_trace_events_well_formed_and_jsonl_roundtrip(cfg, params, tmp_path):
     events = eng.telemetry.trace.chrome_events()
     validate_chrome_events(events)
     names = {e["name"] for e in events}
-    assert {"request", "queued", "prefill", "decode",
-            "prefill_chunk", "draft", "verify"} <= names
-    # request-lifecycle spans live on the slot lanes, dispatch spans on the
-    # engine lane, so Perfetto shows per-slot timelines under the dispatches
+    assert {"request", "queued", "prefill", "decode", "serve.prefill_chunk",
+            "serve.draft", "serve.verify"} <= names
+    # request-lifecycle spans live on the slot lanes, engine-loop spans on
+    # the engine lane, so Perfetto shows per-slot timelines under the loop
     assert {e["tid"] for e in events if e["name"] == "request"} \
         <= set(range(eng.slots))
     assert all(e["tid"] == eng.telemetry.ENGINE_TID
-               for e in events if e["name"] == "prefill_chunk")
+               for e in events if e["name"].startswith("serve."))
     path = tmp_path / "trace.jsonl"
     n = eng.telemetry.trace.export_jsonl(str(path))
     from repro.serve.telemetry import load_trace_jsonl
@@ -251,6 +272,138 @@ def test_spec_acceptance_series_per_slot(cfg, params):
     assert total == eng.stats["spec_accepted_tokens"]
     # the per-request acceptance trace mirrors the slot series
     assert sum(a for r in done for a in r.trace.spec_accepts) == total
+
+
+# --------------------------------------------------------------------------- #
+# engine-loop spans, on the tracer and in the profiler's trace
+# --------------------------------------------------------------------------- #
+PHASES = ("serve.admit", "serve.prefill_chunk", "serve.decode_step",
+          "serve.spec_round", "serve.occupancy")
+
+
+def _parent(name):
+    """The span a span must lie inside: phases in ``serve.iterate``, draft
+    and verify in ``serve.spec_round``, ``serve.<phase>.<part>`` in its
+    phase."""
+    if name in PHASES:
+        return "serve.iterate"
+    if name in ("serve.draft", "serve.verify"):
+        return "serve.spec_round"
+    head = name.rsplit(".", 1)[0]
+    assert head in PHASES, name
+    return head
+
+
+@pytest.fixture(scope="module", params=[0, 2], ids=["plain", "spec_k=2"])
+def profiled(request, cfg, params, tmp_path_factory):
+    """A tiny engine run under ``jax.profiler`` on the CPU: its tracer spans,
+    the profiler's ``serve.*`` host spans, the number of ``_iterate`` calls,
+    and where each host read of a device array happened."""
+    spec_k = request.param
+    _engine(cfg, params, spec_k=spec_k).run(_requests())  # compile first
+    eng = _engine(cfg, params, spec_k=spec_k)
+    calls, stack, reads = [0], [], []
+    inner_iterate, inner_span = eng._iterate, eng.telemetry.span
+
+    def iterate(sched):
+        calls[0] += 1
+        inner_iterate(sched)
+
+    @contextlib.contextmanager
+    def span(name, **args):
+        stack.append(name)
+        try:
+            with inner_span(name, **args):
+                yield
+        finally:
+            stack.pop()
+
+    class Numpy:  # numpy, noting each device array the loop reads back
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kw):
+            if isinstance(a, jax.Array) and stack:
+                reads.append(stack[-1])
+            return np.asarray(a, *args, **kw)
+
+    eng._iterate, eng.telemetry.span = iterate, span
+    d = str(tmp_path_factory.mktemp(f"profile_{spec_k}"))
+    with pytest.MonkeyPatch.context() as mp:
+        for mod in (engine_mod, speculative, paged, protocol):
+            mp.setattr(mod, "np", Numpy())
+        jax.profiler.start_trace(d)
+        try:
+            eng.run(_requests())
+        finally:
+            jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
+    prof = sorted((e.name, e.start_ns * 1e-9, (e.start_ns + e.duration_ns) * 1e-9)
+                  for plane in jax.profiler.ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("serve."))
+    return dict(spans=_tracer_spans(eng.telemetry), prof=prof, iterations=calls[0],
+                reads=reads, spec_k=spec_k)
+
+
+def test_one_iterate_span_per_engine_iteration(profiled):
+    n = profiled["iterations"]
+    assert n > 0
+    assert sum(sp[0] == "serve.iterate" for sp in profiled["spans"]) == n
+    assert sum(sp[0] == "serve.iterate" for sp in profiled["prof"]) == n
+
+
+def test_every_span_lies_inside_its_parent(profiled):
+    spans = profiled["spans"]
+    names = {sp[0] for sp in spans}
+    assert {"serve.iterate", "serve.admit", "serve.prefill_chunk.args",
+            "serve.prefill_chunk.launch", "serve.prefill_chunk.sample",
+            "serve.prefill_chunk.wait", "serve.occupancy.wait"} <= names
+    if profiled["spec_k"]:
+        assert {"serve.spec_round", "serve.spec_round.wait", "serve.draft",
+                "serve.verify"} <= names
+    else:
+        assert {"serve.decode_step.args", "serve.decode_step.launch",
+                "serve.decode_step.wait"} <= names
+        assert "serve.spec_round" not in names
+    for name, t0, t1 in spans:
+        if name == "serve.iterate":
+            continue
+        outer = _parent(name)
+        assert any(p0 <= t0 <= t1 <= p1 for p, p0, p1 in spans if p == outer), \
+            (name, outer)
+        if name.endswith(".wait"):  # a wait lies inside its dispatch span
+            assert outer in ("serve.prefill_chunk", "serve.decode_step",
+                             "serve.spec_round", "serve.occupancy")
+
+
+def test_every_host_read_in_the_loop_is_a_wait(profiled):
+    """Each device array the engine loop reads back sits in a ``.wait``."""
+    assert profiled["reads"]
+    assert all(name.endswith(".wait") for name in profiled["reads"]), \
+        sorted(set(profiled["reads"]))
+
+
+def test_tracer_spans_map_onto_the_profiler_by_one_offset(profiled):
+    """The tracer's clock is process-wide: one offset maps every tracer span
+    onto its profiler copy, within 0.5 ms over the whole run. The tracer
+    stamps just outside the annotation, so with ``c`` the offset each copy
+    starts at least ``c`` after its tracer span starts and ends at most
+    ``c`` after it ends; such a ``c`` exists for every span at once."""
+    prof, mine = {}, {}
+    for name, t0, t1 in profiled["prof"]:
+        prof.setdefault(name, []).append((t0, t1))
+    for name, t0, t1 in profiled["spans"]:
+        mine.setdefault(name, []).append((t0, t1))
+    assert set(mine) == set(prof)
+    starts, ends = [], []
+    for name, spans in mine.items():
+        assert len(spans) == len(prof[name]), name
+        for (t0, t1), (p0, p1) in zip(sorted(spans), prof[name]):
+            starts.append(p0 - t0)
+            ends.append(p1 - t1)
+    assert max(ends) - min(starts) < 5e-4
 
 
 # --------------------------------------------------------------------------- #

@@ -85,15 +85,21 @@ def _serving_fn(mode, quant, upper):
     return fn
 
 
-@pytest.mark.parametrize("mode,C", [
-    ("auto", 1),        # decode wave -> latency (single-query tiles)
-    ("auto", CHUNK),    # chunked prefill -> throughput (8-query tiles)
-    ("latency", 16),    # a chunk forced through single-query tiles
+@pytest.mark.parametrize("mode,C,name", [
+    # decode wave -> latency (single-query tiles)
+    ("auto", 1, "mra_chunk_attn_latency"),
+    # chunked prefill -> throughput (8-query tiles)
+    ("auto", CHUNK, "mra_chunk_attn_throughput"),
+    # a chunk forced through single-query tiles
+    ("latency", 16, "mra_chunk_attn_latency"),
 ], ids=["latency-decode", "throughput-chunk", "latency-forced-chunk"])
-def test_serving_kernel_compiles(one_chip, mode, C):
+def test_serving_kernel_compiles(one_chip, mode, C, name):
+    """The kernel compiles, and each tile mode's custom call carries its own
+    name, so a device trace can tell the two instantiations apart."""
     text = _compile_text(_serving_fn(mode, quant=False, upper=False),
                          _serving_args(one_chip, C, jnp.bfloat16))
-    assert "tpu_custom_call" in text
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls and all(f"%{name}" in line for line in calls)
 
 
 @pytest.mark.parametrize("C", [1, CHUNK], ids=["decode", "chunk"])
