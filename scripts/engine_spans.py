@@ -8,7 +8,9 @@ from the root of a checkout, on the chip. It runs the cell as
 ``Options.engine_hook``, which times every ``Engine._iterate`` and, with
 ``--telemetry 0``, rebuilds the engine's telemetry disabled. The last line
 of standard output is one JSON object: the run's result, and the host time
-of the window's decode-only iterations (no prefill dispatch in them). With
+of the window's decode-only iterations (no prefill dispatch in them), and
+the share of prefill rows without a prompt token (``prefill_rows_skipped``
+over the rows dispatched) in set-up and in the window. With
 ``--trace 1`` it also holds ``bench/phases.py``'s summary of the traced
 window (the engine's host time per iteration, the device's idle time by
 host phase) and, with ``--events``, writes the trace's events (the format of
@@ -40,7 +42,8 @@ def measure(cell, seed: int, seconds: float, trace: bool, telemetry: bool,
 
     from bench import phases, run, trace_reduce
 
-    iterations = []   # (begin, end, prefill dispatches in it)
+    iterations = []   # (begin, end, prefill dispatches, rows skipped in it)
+    rows = {}         # chunk rows per prefill dispatch (slots x chunk)
     marks = {}        # when the profiler started and stopped
 
     def hook(eng):
@@ -48,12 +51,15 @@ def measure(cell, seed: int, seconds: float, trace: bool, telemetry: bool,
             eng.config = eng.config.replace(telemetry=False)
             eng.reset_stats()
         inner = eng._iterate
+        rows["per_dispatch"] = eng.slots * eng.chunk
 
         def iterate(sched):
             n, t0 = eng.stats["prefill_dispatches"], time.perf_counter()
+            k = eng.stats["prefill_rows_skipped"]
             inner(sched)
             iterations.append((t0, time.perf_counter(),
-                               eng.stats["prefill_dispatches"] - n))
+                               eng.stats["prefill_dispatches"] - n,
+                               eng.stats["prefill_rows_skipped"] - k))
 
         eng._iterate = iterate
 
@@ -89,11 +95,23 @@ def measure(cell, seed: int, seconds: float, trace: bool, telemetry: bool,
     else:  # the window opens at set-up's end and closes with the last iteration
         t_open = run.T_START + result["metrics"]["setup_s"]["value"]
         t_close = float("inf")
-    inside = [(b, e, p) for b, e, p in iterations if b >= t_open and e <= t_close]
+    inside = [it for it in iterations if it[0] >= t_open and it[1] <= t_close]
+    setup = [it for it in iterations if it[1] <= t_open]
+
+    def skipped(its):
+        dispatched = sum(it[2] for it in its) * rows["per_dispatch"]
+        n = sum(it[3] for it in its)
+        return {"prefill_dispatches": sum(it[2] for it in its),
+                "rows_dispatched": dispatched, "prefill_rows_skipped": n,
+                "share": n / dispatched if dispatched else None}
+
     out = {"workload": cell.name, "seed": seed, "trace": int(trace),
            "telemetry": int(telemetry), "result": result,
-           "decode_iteration_s": _quartiles([e - b for b, e, p in inside if not p]),
-           "iterations": len(inside)}
+           "decode_iteration_s": _quartiles([it[1] - it[0] for it in inside
+                                             if not it[2]]),
+           "iterations": len(inside),
+           # prefill rows with no prompt token, attended as q_pos = -1
+           "rows_skipped": {"setup": skipped(setup), "window": skipped(inside)}}
     if trace:
         out["phases"] = summary
     return out

@@ -387,7 +387,8 @@ def mra2_chunk_attention(
     Args:
       q: (B, Hq, C, D) chunk queries; their K/V must already be in the cache.
       lengths: (B,) total written length (chunk included).
-      q_pos: (B, C) global position of each query token.
+      q_pos: (B, C) global position of each query token; -1 marks a row
+        that carries no query (it selects no page and comes out zero).
       page_blocks: (B, nb) ring page table; None = dense identity layout.
 
     Returns:
@@ -427,10 +428,12 @@ def mra2_chunk_attention(
     if up is not None and cfg.variant == "full":
         # Collapsed levels + tail (DESIGN.md §14): score the per-entry means.
         # Entries hold only *evicted* (strictly past) tokens, so there is no
-        # causal mask — liveness (count > 0) is the only gate. Their maxima
-        # join the row stabilizer: collapsed history can dominate the live
-        # window, and the background exp must not overflow.
-        hlive = (up.counts > 0)[:, None, None, None, :]  # (B,1,1,1,NU)
+        # causal mask — liveness (count > 0) and a real query row (q_pos >=
+        # 0) are the only gates. Their maxima join the row stabilizer:
+        # collapsed history can dominate the live window, and the
+        # background exp must not overflow.
+        hlive = ((up.counts > 0)[:, None, None, None, :]
+                 & (q_pos >= 0)[:, None, None, :, None])  # (B,1,1,C,NU)
         hmu = jnp.einsum(
             "bhgcd,bhyd->bhgcy", qg, up.k_mean.astype(cdt)) * scale
         hmu = jnp.where(hlive, hmu, NEG_INF)

@@ -53,6 +53,13 @@ prefill/verify chunks with C == chunk. ``EngineConfig.kernel_mode`` forces
 one mode for every dispatch. Ragged chunks (C not a multiple of C_tile) are
 padded with ``q_pos = -1`` rows, which select nothing and are sliced off.
 
+The ``q_pos = -1`` contract (DESIGN.md §11): such a row carries no query.
+It selects no page, adds nothing to its tile's fetch union and comes out as
+exact zeros, and a grid step whose tile holds only such rows runs no
+iteration of the page loop. ``prefill_chunk`` passes -1 for every row
+without a prompt token: the rows of decoding slots and those past a ragged
+chunk's end.
+
 Forward-only by design: the serving path is never differentiated (training
 uses the §3 kernels). Differentiating through this op raises at trace time.
 """
@@ -93,7 +100,7 @@ def _dot(a, b_, dims):
 def _chunk_kernel(
     # VMEM tiles
     q_ref,       # (1, G, Ct, D) query tile (fp32); (1, Ct, G, D) in latency
-    qpos_ref,    # (1, G, Ct, 1) int32 global positions (-1 = padded row),
+    qpos_ref,    # (1, G, Ct, 1) int32 global positions (-1 = no query),
                  # laid out like q_ref
     kds_ref,     # (1, nb, D) per-page K means (coarse scoring keys)
     vds_ref,     # (1, nb, D) per-page V means (coarse background values)
@@ -217,17 +224,22 @@ def _chunk_kernel(
 
         return 0
 
-    jax.lax.fori_loop(0, nb, page_body, 0)
+    # a tile none of whose rows carries a query (every q_pos < 0: rows past a
+    # chunk's num_valid, or the tile padding) selects no page, so its loop
+    # runs no iteration; its output normalizes to exact zeros below
+    n_pages = jnp.where(jnp.max(qp) >= 0, nb, 0)
+    jax.lax.fori_loop(0, n_pages, page_body, 0)
 
     # ---- background + two-level stabilizer + normalize ---------------------
     c = jnp.maximum(jnp.max(coarse_m, axis=1, keepdims=True), NEG_INF * 0.5)
     if include_bg and with_upper:
         # H-level hierarchy (DESIGN.md §14): score the resident collapsed-
         # level + tail means. Entries hold only evicted (strictly past)
-        # tokens — liveness is the one gate — and their maxima join the row
-        # stabilizer before any exp: far history can dominate the window.
+        # tokens — liveness and a real query row are the gates — and their
+        # maxima join the row stabilizer before any exp: far history can
+        # dominate the window.
         hmu = _dot(q, hk_ref[0], ((1,), (1,))) * scale   # (rows, NU)
-        hlive = hcnt_ref[0] > 0.0                        # (1, NU)
+        hlive = (hcnt_ref[0] > 0.0) & (qp >= 0)          # (rows, NU)
         hmu = jnp.where(hlive, hmu, NEG_INF)
         c = jnp.maximum(c, jnp.max(hmu, axis=1, keepdims=True))
     mt = mt_ref[...]

@@ -335,6 +335,8 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, num_valid, *,
       tokens: (B, C) int32 prompt chunk per slot (padding arbitrary).
       num_valid: (B,) int32 count of real tokens in each slot's chunk;
         0 freezes the slot for this call (cache rows preserved bit-for-bit).
+        Rows past it attend as ``q_pos = -1`` and come out of attention
+        zero: their outputs and logits are meaningless.
       all_logits: return logits at every chunk position, not just the last
         valid one — speculative verify needs the target distribution after
         each draft.
@@ -351,6 +353,11 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, num_valid, *,
     offsets = cache["lengths"]  # (B,)
     positions = offsets[:, None] + jnp.arange(C, dtype=offsets.dtype)  # (B,C)
     tv = jnp.arange(C) < num_valid[:, None]  # (B,C) chunk-token validity
+    # a row with no prompt token (a decoding slot's, or past a ragged chunk's
+    # end) attends as q_pos = -1: it selects no page, and a kernel tile of
+    # such rows runs no page loop; RoPE, the writes and the page table keep
+    # the real positions
+    q_pos = jnp.where(tv, positions, -1)
     lengths_new = offsets + num_valid.astype(offsets.dtype)
     x = L.embed(tokens, params["embed"], cfg, positions=positions)
     new_cache = dict(cache)
@@ -468,7 +475,7 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens, num_valid, *,
                 new_cache["page_blocks"] = jnp.where(
                     touched, blk_new, new_cache["page_blocks"])
         o = chunk_attention(
-            q, kc, vc, lengths_new, positions, cfg.attn_spec, pyramid=pyramid,
+            q, kc, vc, lengths_new, q_pos, cfg.attn_spec, pyramid=pyramid,
             page_blocks=new_cache.get("page_blocks"), k_scale=ks, v_scale=vs)
         if cfg.padded_heads != cfg.num_heads:
             o = o * L.head_mask(cfg)[None, :, None, None].astype(o.dtype)

@@ -268,7 +268,7 @@ class Engine:
         m = tel.metrics
         m.declare_counter(
             "prefill_dispatches", "decode_dispatches", "prefill_tokens",
-            "generated_tokens", "requests_completed",
+            "prefill_rows_skipped", "generated_tokens", "requests_completed",
             # speculative decoding (spec_k > 0; serve/speculative.py)
             "spec_rounds", "draft_dispatches", "verify_dispatches",
             "spec_drafted_tokens", "spec_accepted_tokens",
@@ -369,6 +369,9 @@ class Engine:
                     first = np.asarray(first)
             tel.metrics.inc("prefill_dispatches")
             tel.metrics.inc("prefill_tokens", int(num_valid.sum()))
+            # dispatched rows with no prompt token: they attend nothing
+            tel.metrics.inc("prefill_rows_skipped",
+                            int(tokens.size - num_valid.sum()))
             for s in finishing:
                 tel.on_prefill_done(sched.slots[s].req)
                 sched.on_sampled(s, first[s])

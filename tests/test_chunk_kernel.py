@@ -143,12 +143,19 @@ FORCED = [Case(), Case(paged=True, quant=True, seed=21),
 
 @pytest.mark.parametrize("case", FORCED, ids=lambda c: c.id)
 @pytest.mark.parametrize("mode", ["latency", "throughput"])
-@pytest.mark.parametrize("C", [1, 5])
-def test_kernel_forced_modes_match_jnp(case: Case, mode: str, C: int):
+@pytest.mark.parametrize("C,masked", [(1, False), (5, False), (5, True)],
+                         ids=["C1", "C5", "C5-no-query"])
+def test_kernel_forced_modes_match_jnp(case: Case, mode: str, C: int,
+                                       masked: bool):
     """Each forced tile shape == jnp at both a decode (C=1) and a *ragged*
     chunk width (C=5: not a multiple of the throughput C_tile, so the padded
-    tail rows must select nothing and slice away cleanly)."""
+    tail rows must select nothing and slice away cleanly) — and on a chunk
+    whose rows past 2 in slot 0 and every row of slot 1 carry no query
+    (q_pos = -1, as prefill_chunk passes them), which both routes return
+    as exact zeros."""
     q, k, v, lengths, q_pos, pb, ks, vs = make_case_inputs(case, C=C)
+    if masked:
+        q_pos = jnp.where(jnp.asarray([[2], [-1]]) >= jnp.arange(C), q_pos, -1)
     m = 1 if case.coarse_only else case.m
     cfg, cfgk = _cfgs(case, mode)
     kw = dict(decode_blocks=m, page_blocks=pb, k_scale=ks, v_scale=vs)
@@ -156,6 +163,11 @@ def test_kernel_forced_modes_match_jnp(case: Case, mode: str, C: int):
     out = mra2_chunk_attention(q, k, v, lengths, q_pos, cfgk, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=1e-5)
+    none = (np.asarray(q_pos) < 0)  # (B, C)
+    for got in (out, ref):
+        rows = np.asarray(got).transpose(0, 2, 1, 3)[none]
+        assert (rows.size > 0) == masked
+        assert np.abs(rows).max(initial=0.0) == 0.0
 
 
 @pytest.mark.parametrize("mode", ["latency", "throughput"])
@@ -201,6 +213,93 @@ def test_fresh_slot_zero_live_query_block_is_zero(route: str):
         ref = mra2_chunk_attention(q, k, v, lengths, q_pos, cfg,
                                    decode_blocks=case.m)
         np.testing.assert_allclose(out, np.asarray(ref), atol=2e-5, rtol=1e-5)
+
+
+# --------------------------------------------------------------------------- #
+# Rows with no query (q_pos = -1): what prefill_chunk passes for the rows of
+# decoding slots and the rows past a ragged chunk's end (DESIGN.md §11).
+# --------------------------------------------------------------------------- #
+NO_QUERY_C = 12  # one full 8-row throughput tile + a padded 4-row one
+
+
+def _no_query_inputs(kind: str):
+    """(q, k, v, lengths, real q_pos, masked q_pos, kw) for a 3-slot chunk:
+    slot 0 prefills all 12 rows, slot 1 decodes (no prompt token: every row
+    is -1 in the masked q_pos), slot 2 ends its prompt after 5 rows, so its
+    second throughput tile holds no query at all. ``kind`` is "fp", "int8"
+    (quantized pages) or "upper" (an H-level hierarchy's resident tiles)."""
+    case = Case(B=3, group=2, quant=kind == "int8", seed=17)
+    q, k, v, _, _, _, ks, vs = make_case_inputs(case, C=NO_QUERY_C)
+    C = NO_QUERY_C
+    offsets = jnp.asarray([28, 50, 30], jnp.int32)
+    num_valid = jnp.asarray([C, 0, 5], jnp.int32)
+    lengths = offsets + num_valid
+    real = offsets[:, None] + jnp.arange(C, dtype=jnp.int32)
+    masked = jnp.where(jnp.arange(C) < num_valid[:, None], real, -1)
+    kw = dict(decode_blocks=case.m, k_scale=ks, v_scale=vs)
+    if kind == "upper":
+        from repro.core.hier import HierUpper
+
+        B, Hkv, S, D, b = case.B, case.Hkv, case.S, case.D, case.b
+        nb, nu = S // b, 3
+        kf = k.astype(jnp.float32)
+        vf = v.astype(jnp.float32)
+        mask = paged_position_mask(lengths, identity_page_table(B, nb), S, b)
+        mask = mask.astype(jnp.float32)[:, None, :, None]
+        r = np.random.default_rng(case.seed)
+        upper = HierUpper(
+            jnp.asarray(r.standard_normal((B, Hkv, nu, D)), jnp.float32),
+            jnp.asarray(r.standard_normal((B, Hkv, nu, D)), jnp.float32),
+            jnp.asarray([[16.0, 0.0, 5.0]] * B, jnp.float32))
+        kw["pyramid"] = PyramidState(
+            jnp.sum((kf * mask).reshape(B, Hkv, nb, b, D), axis=3),
+            jnp.sum((vf * mask).reshape(B, Hkv, nb, b, D), axis=3), upper)
+    return case, q, k, v, lengths, real, masked, kw
+
+
+NO_QUERY_KINDS = ["fp", "int8", "upper"]
+
+
+@pytest.mark.parametrize("kind", NO_QUERY_KINDS)
+@pytest.mark.parametrize("mode", ["latency", "throughput"])
+def test_no_query_rows_leave_valid_rows_bit_identical(kind: str, mode: str):
+    """Setting the other rows' q_pos to -1 changes nothing in the rows that
+    carry a query: same pages, same arithmetic, the same bits. The no-query
+    rows come out as exact zeros on both routes: a whole tile of them (a
+    decoding slot's chunk, the tail of a ragged one), those inside a tile
+    that holds queries too, and an all -1 chunk."""
+    case, q, k, v, lengths, real, masked, kw = _no_query_inputs(kind)
+    cfg, cfgk = _cfgs(case, mode)
+    before = np.asarray(mra2_chunk_attention(q, k, v, lengths, real, cfgk, **kw))
+    after = np.asarray(mra2_chunk_attention(q, k, v, lengths, masked, cfgk,
+                                            **kw))
+    valid = np.asarray(masked) >= 0  # (B, C)
+    for s in range(case.B):
+        np.testing.assert_array_equal(after[s][:, valid[s]],
+                                      before[s][:, valid[s]])
+    assert np.abs(after[1]).max() == 0.0          # the decoding slot
+    assert np.abs(after[2][:, 8:]).max() == 0.0   # slot 2's all-pad tile
+    assert np.abs(after[2][:, 5:8]).max() == 0.0  # pad rows of a mixed tile
+    assert np.abs(after[0]).min(axis=-1).max() > 0.0 and valid[0].all()
+    ref = np.asarray(mra2_chunk_attention(q, k, v, lengths, masked, cfg, **kw))
+    assert np.abs(ref.transpose(0, 2, 1, 3)[~valid]).max() == 0.0
+    none = jnp.full_like(masked, -1)
+    out = np.asarray(mra2_chunk_attention(q, k, v, lengths, none, cfgk, **kw))
+    assert np.abs(out).max() == 0.0
+
+
+@pytest.mark.parametrize("local_window", [None, 4])
+def test_dense_routes_no_query_rows_are_zero(local_window):
+    """The exact (non-MRA) chunk routes — full and local attention — see
+    the same q_pos = -1 rows from prefill_chunk: they allow no key and must
+    come out as finite zeros, not NaN, while query rows still attend."""
+    case, q, k, v, lengths, _, masked, _ = _no_query_inputs("fp")
+    out = np.asarray(full_chunk_attention(q, k, v, lengths, masked,
+                                          local_window=local_window))
+    assert np.isfinite(out).all()
+    pad = ~(np.asarray(masked) >= 0)
+    assert np.abs(out.transpose(0, 2, 1, 3)[pad]).max() == 0.0
+    assert np.abs(out.transpose(0, 2, 1, 3)[~pad]).min(axis=-1).max() > 0.0
 
 
 def test_bad_shapes_raise_value_errors():
@@ -323,27 +422,90 @@ def _engine_requests():
     ]
 
 
-def test_engine_kernel_path_matches_jnp_engine():
-    """Ragged continuous batching through the fused kernel emits identical
-    token streams (chunked prefill + decode waves both route through it) —
-    under the per-dispatch "auto" mode pick AND with either tile shape
-    forced via EngineConfig.kernel_mode (DESIGN.md §11 dual-mode contract)."""
+def _no_query_requests():
+    """5 requests over 3 slots at chunk 8: the prefills see 0, 1 and 2 (all
+    the other) slots decoding, and most prompts end in a ragged chunk — the
+    rows prefill_chunk attends as q_pos = -1."""
+    from repro.serve import Request, SamplingParams
+
+    return [Request(prompt=np.arange(1, 20), max_new_tokens=12),
+            Request(prompt=np.array([5, 11, 2]), max_new_tokens=4),
+            Request(prompt=np.arange(2, 12), max_new_tokens=8,
+                    sampling=SamplingParams(temperature=0.9, seed=5)),
+            Request(prompt=np.arange(3, 16), max_new_tokens=3),
+            Request(prompt=np.arange(7, 28), max_new_tokens=2)]
+
+
+def _smoke_engine_parts():
     from repro.configs import get_smoke_config
     from repro.models import get_model, init_params
-    from repro.serve import Engine, EngineConfig
+    from repro.serve import EngineConfig
 
     cfg = get_smoke_config("qwen3-1.7b")
     params = init_params(get_model(cfg).param_specs(cfg), jax.random.PRNGKey(0))
-    ecfg = EngineConfig(slots=3, max_len=64, chunk=8)
-    ref = Engine(cfg, params, ecfg).run(_engine_requests())
-    by = {len(r.prompt): r.out for r in ref}
+    return cfg, params, EngineConfig(slots=3, max_len=64, chunk=8)
+
+
+@pytest.mark.parametrize("requests", [_engine_requests, _no_query_requests],
+                         ids=["sampled", "decoding-slots"])
+def test_engine_kernel_path_matches_jnp_engine(requests):
+    """Ragged continuous batching through the fused kernel emits identical
+    token streams (chunked prefill + decode waves both route through it) —
+    under the per-dispatch "auto" mode pick AND with either tile shape
+    forced via EngineConfig.kernel_mode (DESIGN.md §11 dual-mode contract);
+    also when prefills run beside 0, 1 and 2 decoding slots."""
+    from repro.serve import Engine
+
+    cfg, params, ecfg = _smoke_engine_parts()
+    eng = Engine(cfg, params, ecfg)
+    plans, prefill = [], eng._prefill
+
+    def spy(params, tree, tokens, num_valid):
+        plans.append(np.asarray(num_valid))
+        return prefill(params, tree, tokens, num_valid)
+
+    eng._prefill = spy
+    by = {len(r.prompt): r.out for r in eng.run(requests())}
+    if requests is _no_query_requests:
+        # the traffic the case is for: 0, 1 and 2 of 3 slots without a
+        # prompt token beside a prefill, and ragged chunk ends
+        assert {0, 1, 2} <= {int((nv == 0).sum()) for nv in plans}
+        assert any(0 < v < ecfg.chunk for nv in plans for v in nv)
     kcfg = cfg.replace(attn_use_kernel=True, attn_interpret=True)
     for mode in ("auto", "latency", "throughput"):
         got = Engine(kcfg, params, ecfg.replace(kernel_mode=mode)).run(
-            _engine_requests())
+            requests())
+        assert sorted(len(r.prompt) for r in got) == sorted(by)
         for r in got:
             np.testing.assert_array_equal(r.out, by[len(r.prompt)],
                                           err_msg=f"kernel_mode={mode}")
+
+
+def test_engine_kernel_prefill_lowered_once():
+    """One jitted prefill program serves every mix of rows with and without
+    a prompt token: the mask travels as data, so it is lowered once (counted
+    through ``jax.monitoring``, as bench/run.py counts programs lowered)."""
+    from repro.serve import Engine
+    from repro.serve import engine as engine_mod
+
+    cfg, params, ecfg = _smoke_engine_parts()
+    kcfg = cfg.replace(attn_use_kernel=True, attn_interpret=True)
+    engine_mod._make_engine_fns.cache_clear()  # a fresh prefill to lower
+    lowered = []
+
+    def on(name, _secs, fun_name="", **_kw):
+        if (name == "/jax/core/compile/jaxpr_to_mlir_module_duration"
+                and fun_name == "jit(prefill_chunk)"):
+            lowered.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        eng = Engine(kcfg, params, ecfg)
+        eng.run(_no_query_requests())
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert eng.stats["prefill_dispatches"] >= 5
+    assert lowered == ["jit(prefill_chunk)"]
 
 
 def test_engine_rejects_unknown_kernel_mode():

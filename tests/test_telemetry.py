@@ -108,7 +108,7 @@ def test_reset_stats_declares_every_writer_key(cfg, params):
     assert eng.stats["spec_rounds"] > 0
     eng.reset_stats()
     for key in ("prefill_dispatches", "decode_dispatches", "prefill_tokens",
-                "generated_tokens", "requests_completed", "spec_rounds",
+                "prefill_rows_skipped", "generated_tokens", "requests_completed", "spec_rounds",
                 "draft_dispatches", "verify_dispatches", "spec_drafted_tokens",
                 "spec_accepted_tokens", "spec_emitted_tokens"):
         assert eng.stats[key] == 0, key
@@ -116,6 +116,29 @@ def test_reset_stats_declares_every_writer_key(cfg, params):
     # and the engine can serve again with the fresh registry
     done = eng.run(_requests()[:1])
     assert len(done) == 1 and eng.stats["spec_rounds"] >= 0
+
+
+def test_prefill_rows_skipped_counts_rows_without_a_prompt_token(cfg, params):
+    """``prefill_rows_skipped`` adds ``slots * chunk - num_valid.sum()`` per
+    prefill dispatch — the rows that attend as q_pos = -1 — and is in the
+    engine's stats, with telemetry on and off (a plain counter)."""
+    for telemetry in (True, False):
+        eng = _engine(cfg, params, slots=3, telemetry=telemetry)
+        plans, prefill = [], eng._prefill
+
+        def spy(params, tree, tokens, num_valid, prefill=prefill, plans=plans):
+            plans.append(np.asarray(num_valid))
+            return prefill(params, tree, tokens, num_valid)
+
+        eng._prefill = spy
+        eng.run(_requests())
+        want = sum(eng.slots * eng.chunk - int(nv.sum()) for nv in plans)
+        assert len(plans) == eng.stats["prefill_dispatches"] > 1
+        assert eng.stats["prefill_rows_skipped"] == want > 0
+        assert eng.stats["prefill_tokens"] + want == \
+            len(plans) * eng.slots * eng.chunk
+        snap = eng.telemetry.metrics.snapshot()["counters"]
+        assert snap["prefill_rows_skipped"] == want
 
 
 def test_registry_types_and_bounds():
